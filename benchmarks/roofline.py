@@ -20,9 +20,11 @@ import json
 import os
 import sys
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+from repro.core.qthreshold import V5E
+
+PEAK_FLOPS = V5E.peak_flops
+HBM_BW = V5E.hbm_bw
+ICI_BW = V5E.ici_bw
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results",
                            "dryrun")

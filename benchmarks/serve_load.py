@@ -96,6 +96,8 @@ import numpy as np                                            # noqa: E402
 
 from repro.configs import get_config                          # noqa: E402
 from repro.configs.base import ParallelConfig                 # noqa: E402
+from repro.kernels import pallas_interpret                    # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_host_mesh                  # noqa: E402
 from repro.models.model import MeshShape, build_model         # noqa: E402
 from repro.serve import (FleetRouter, ServeEngine, WallClock,  # noqa: E402
@@ -884,7 +886,7 @@ def decode_attention_microbench():
     k_pool = jax.random.normal(jax.random.fold_in(key, 1), (1, P, Hkv, hd))
     v_pool = jax.random.normal(jax.random.fold_in(key, 2), (1, P, Hkv, hd))
     q = jax.random.normal(jax.random.fold_in(key, 3), (B, 1, Hkv * rep, hd))
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     ref_fn = jax.jit(functools.partial(paged_decode_attention,
                                        block_size=bs))
     fused_fn = jax.jit(functools.partial(paged_attention, block_size=bs,
@@ -984,7 +986,7 @@ def phases_breakdown():
     """
     from repro.core.qthreshold import V5E
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     cells = []
 
     def run_cell(workload, *, fused, prompt_len, gen, chunk, sharing,
@@ -1428,6 +1430,7 @@ def main():
                          "existing --out file (fresh runs leave this "
                          "empty and produce the full file)")
     args = ap.parse_args()
+    print(f"[bench] compile cache {enable_compile_cache()}")
 
     if args.only:
         key, fn = ONLY_SECTIONS[args.only]

@@ -75,7 +75,11 @@ def grouped_ffn(x: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray,
     if not use_pallas:
         return grouped_ffn_ref(x, w_in, w_out, group_sizes_padded,
                                w_gate=w_gate, act=act, block_m=block_m)
+    from repro.kernels.moe_gmm.moe_gmm import pick_block_f
     from repro.kernels.moe_gmm.ops import fused_expert_ffn
+    block_f = pick_block_f(x.shape[1], w_in.shape[2], block_m=block_m,
+                           itemsize=x.dtype.itemsize,
+                           gated=w_gate is not None)
     return fused_expert_ffn(x, w_in, w_out, group_sizes_padded,
-                            w_gate=w_gate, act=act, block_m=block_m,
-                            interpret=interpret)
+                            block_f=block_f, w_gate=w_gate, act=act,
+                            block_m=block_m, interpret=interpret)

@@ -27,10 +27,8 @@ from repro.core import prefetch
 from repro.core import router as R
 from repro.core import scheduler as SCH
 from repro.core.grouped_ffn import grouped_ffn
-from repro.core.qthreshold import q_threshold
+from repro.core.qthreshold import device_hardware, q_threshold
 from repro.core.topology import EPTopology, make_topology
-
-from repro.core.compat import shard_map as _shard_map
 
 
 @dataclass(frozen=True)
@@ -104,7 +102,8 @@ class MoEBlockSpec:
     def q(self) -> int:
         if self.moe.q_tokens:
             return self.moe.q_tokens
-        return q_threshold(ep_degree=self.ep_degree, dense_fetch=True)
+        return q_threshold(device_hardware(), ep_degree=self.ep_degree,
+                           dense_fetch=True)
 
 
 def init_moe_params(key: jax.Array, spec: MoEBlockSpec,
@@ -407,7 +406,7 @@ def tp_moe_block(x: jnp.ndarray, params: Dict[str, jnp.ndarray], *,
         (P() if skew_key is not None else None),
     )
     out_specs = (P(batch_spec, None, None), _diag_out_specs(batch_spec))
-    fn = _shard_map(body, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     return fn(x, params["router"], params["w_in"], params["w_out"],
               params.get("w_gate"), skew_key)
@@ -506,7 +505,7 @@ def moe_block(x: jnp.ndarray, params: Dict[str, jnp.ndarray], *,
         (P(batch_spec, x_seq_spec) if valid_mask is not None else None),
     )
     out_specs = (P(batch_spec, x_seq_spec, None), _diag_out_specs(batch_spec))
-    fn = _shard_map(body, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     return fn(x, params["router"], params["w_in"], params["w_out"],
               params.get("w_gate"), rep_params, replica_ids, residency_ids,

@@ -15,17 +15,42 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
+
 
 @dataclass(frozen=True)
 class HardwareSpec:
-    """TPU v5e per-chip constants (assignment-provided)."""
-    peak_flops: float = 197e12       # bf16 FLOP/s
-    hbm_bw: float = 819e9            # B/s
-    ici_bw: float = 50e9             # B/s per link
+    """Per-chip peaks Eq. 4 and the cost models read."""
+    peak_flops: float                # bf16 FLOP/s
+    hbm_bw: float                    # B/s
+    ici_bw: float                    # B/s per link
     dtype_bytes: int = 2             # bf16
 
 
-V5E = HardwareSpec()
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+# TPU documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of chip-to-chip interconnect over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": HardwareSpec(peak_flops=197e12, hbm_bw=819e9,
+                                ici_bw=50e9),
+}
+
+# The chip the CPU backend stands in for: CPU runs (tests, simulator)
+# model a v5e, they never measure one.
+V5E = PEAKS["TPU v5 lite"]
+
+
+def device_hardware(device=None) -> HardwareSpec:
+    """Peaks of ``device`` (default: the first device JAX sees).  A TPU
+    kind missing from ``PEAKS`` raises; a non-TPU backend gets the
+    modelled v5e target."""
+    dev = device if device is not None else jax.devices()[0]
+    if dev.platform != "tpu":
+        return V5E
+    if dev.device_kind not in PEAKS:
+        raise KeyError(f"no peaks for TPU kind {dev.device_kind!r}; add a "
+                       f"row with its source to qthreshold.PEAKS")
+    return PEAKS[dev.device_kind]
 
 
 def q_threshold(hw: HardwareSpec = V5E, *, ep_degree: int = 1,

@@ -22,8 +22,8 @@ def tile_group_map(group_sizes_padded: jnp.ndarray, n_tiles: int,
 
 def fused_expert_ffn(x: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray,
                      group_sizes_padded: jnp.ndarray, *,
-                     w_gate: Optional[jnp.ndarray] = None, act: str = "gelu",
-                     block_m: int = 128, block_f: int = 512,
+                     block_f: int, w_gate: Optional[jnp.ndarray] = None,
+                     act: str = "gelu", block_m: int = 128,
                      interpret: bool = False) -> jnp.ndarray:
     M = x.shape[0]
     n_tiles = M // block_m

@@ -1,9 +1,12 @@
 """Jitted wrapper used by models/attention.py (layout adaptation)."""
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.kernels.paged_attention.paged_attention import \
     paged_attention_kernel
@@ -31,7 +34,8 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
                     block_table: jnp.ndarray, cache_len: jnp.ndarray, *,
                     block_size: int, softcap: float = 0.0,
                     q_tile: Optional[int] = None,
-                    interpret: bool = False) -> jnp.ndarray:
+                    interpret: bool = False,
+                    mesh: Optional[jax.sharding.Mesh] = None) -> jnp.ndarray:
     """Model-layout entry: q [B, S, H, hd] with S >= 1 query positions
     (S = 1 is plain decode; S = k + 1 is a speculative-verify window;
     S = chunk is a prefill chunk — always causal within the window);
@@ -51,7 +55,27 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
     S * rep fits one ~512-row tile, else ~512 // rep; S is zero-padded at
     the deep end up to a tile multiple (ragged last tile) and the padded
     outputs are dropped here.
+
+    On a multi-device ``mesh`` the kernel runs per device under
+    ``shard_map`` (XLA cannot partition a Mosaic call): q heads and the
+    pools' kv heads split over the ``model`` axis where the kv head
+    count divides it — the layout the head-sharded projections and the
+    engine's placed pool already have — else every device runs all
+    heads on replicated operands.
     """
+    if mesh is not None and mesh.size > 1:
+        ep = dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
+        heads = PartitionSpec(
+            None, None,
+            "model" if ep > 1 and k_pool.shape[2] % ep == 0 else None)
+        local = functools.partial(paged_attention, block_size=block_size,
+                                  softcap=softcap, q_tile=q_tile,
+                                  interpret=interpret)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(heads, heads, heads, PartitionSpec(),
+                                       PartitionSpec()),
+                             out_specs=heads, check_vma=False)(
+            q, k_pool, v_pool, block_table, cache_len)
     B, S, H, hd = q.shape
     P, Hkv = k_pool.shape[1], k_pool.shape[2]
     rep = H // Hkv

@@ -54,9 +54,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params as _tpu_compiler_params
-
 _NEG_INF = -1e30
+
+
+class PagedLayoutError(ValueError):
+    """The pool's head dim cannot be tiled for the TPU: the kernel reads
+    one kv head's ``[block_size, hd]`` slice of the ``[num_blocks,
+    block_size, Hkv * hd]`` pool view, so ``hd`` must fill whole
+    128-lane tiles."""
 
 
 def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
@@ -85,7 +90,7 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     @pl.when(j * block_size <= hi)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # [q_blk*rep, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)             # [bs, hd]
+        k = k_ref[0].astype(jnp.float32)                   # [bs, hd]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap > 0:
@@ -113,7 +118,7 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
         acc_ref[...] = (acc_ref[...] * corr
-                        + jnp.dot(p.astype(v_ref.dtype), v_ref[0, :, 0],
+                        + jnp.dot(p.astype(v_ref.dtype), v_ref[0],
                                   preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
@@ -143,6 +148,15 @@ def paged_attention_kernel(q: jnp.ndarray, k_pool: jnp.ndarray,
     ``q_tile=None`` means one tile covering all q_len queries (the
     pre-q-tiling layout: no padding, QR == q_len * rep); ``rep`` is then
     derived from the shapes.
+
+    The pool is read through the view ``[num_blocks, block_size, Hkv *
+    hd]`` (a free merge of the two contiguous trailing dims): a K/V block
+    is then ``(1, block_size, hd)`` at lane offset ``g * hd``, whose
+    trailing dims the TPU can tile, where a ``(1, block_size, 1, hd)``
+    block of the 4-D pool is refused (a second-minor block dim of 1
+    against ``Hkv``).  Compiling for the TPU therefore needs
+    ``hd % 128 == 0`` (or a single kv head); otherwise
+    ``PagedLayoutError`` is raised.
     """
     B, Hkv, QR, hd = q.shape
     if q_tile is None:
@@ -156,6 +170,14 @@ def paged_attention_kernel(q: jnp.ndarray, k_pool: jnp.ndarray,
     assert n_q_tiles * q_tile >= q_len, (n_q_tiles, q_tile, q_len)
     n_blocks = block_table.shape[1]
     assert k_pool.shape[1] == block_size and k_pool.shape[2] == Hkv
+    if not interpret and Hkv > 1 and hd % 128:
+        raise PagedLayoutError(
+            f"paged attention on the TPU needs head_dim % 128 == 0 to read "
+            f"one kv head's lanes of the [num_blocks, block_size, Hkv*hd] "
+            f"pool view; got head_dim {hd} with {Hkv} kv heads")
+    num_pool_blocks = k_pool.shape[0]
+    k_view = k_pool.reshape(num_pool_blocks, block_size, Hkv * hd)
+    v_view = v_pool.reshape(num_pool_blocks, block_size, Hkv * hd)
     scale = hd ** -0.5
     grid = (B, Hkv, n_q_tiles, n_blocks)
 
@@ -168,14 +190,14 @@ def paged_attention_kernel(q: jnp.ndarray, k_pool: jnp.ndarray,
         # then map to the same physical block and Pallas elides the copy
         hi = cl[b] - q_len + jnp.minimum((t + 1) * q_tile, q_len) - 1
         jj = jnp.clip(jnp.minimum(j, hi // block_size), 0, n_blocks - 1)
-        return (bt[b, jj], 0, g, 0)
+        return (bt[b, jj], 0, g)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, tile_rows, hd), q_index),
-            pl.BlockSpec((1, block_size, 1, hd), kv_index),
-            pl.BlockSpec((1, block_size, 1, hd), kv_index),
+            pl.BlockSpec((1, block_size, hd), kv_index),
+            pl.BlockSpec((1, block_size, hd), kv_index),
         ],
         out_specs=pl.BlockSpec((1, 1, tile_rows, hd), q_index),
         scratch_shapes=[
@@ -189,9 +211,9 @@ def paged_attention_kernel(q: jnp.ndarray, k_pool: jnp.ndarray,
                           q_blk=q_tile, rep=rep),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, QR, hd), q.dtype),
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret)
     return fn(block_table.astype(jnp.int32), cache_len.astype(jnp.int32),
-              q, k_pool, v_pool)
+              q, k_view, v_view)

@@ -40,7 +40,9 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.base import ParallelConfig
 from repro.core.topology import static_opt_placement
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
+from repro.launch.shardings import param_shardings
 from repro.models.model import MeshShape, build_model
 from repro.serve import (EngineConfig, FleetRouter, ROUTING_POLICIES,
                          ServeEngine, WallClock, engine_config_for,
@@ -182,8 +184,12 @@ def _mesh_and_model(args, cfg, prompt_len):
     ms = MeshShape(tuple(zip(mesh.axis_names, mesh.devices.shape)))
     model = build_model(cfg, pcfg, batch=args.batch, seq_len=prompt_len,
                         mesh_shape=ms, mesh=mesh)
-    with mesh:
-        params = model.init(jax.random.PRNGKey(0))
+    # initialise every leaf in place on its own shard: a model that does
+    # not fit one device never lands whole on the first
+    key = jax.random.PRNGKey(0)
+    shardings = param_shardings(jax.eval_shape(model.init, key), cfg, pcfg,
+                                mesh)
+    params = jax.jit(model.init, out_shardings=shardings)(key)
     return mesh, model, params
 
 
@@ -376,7 +382,9 @@ def serve_fleet(args, cfg, requests, prompt_len, gen):
     return rep
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI's flags (``chip_smoke.py`` drives the same
+    parser, so its runs are CLI runs)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -436,7 +444,13 @@ def main():
     ap.add_argument("--trace", default="",
                     help="JSON trace file of arrival records")
     ap.add_argument("--out", default="", help="write the report JSON here")
-    serve(ap.parse_args())
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    print(f"[serve] compile cache {enable_compile_cache()}")
+    serve(args)
 
 
 if __name__ == "__main__":

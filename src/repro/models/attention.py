@@ -313,6 +313,7 @@ def attention_block(x: jnp.ndarray, p: Dict[str, jnp.ndarray],
                     block_table: Optional[jnp.ndarray] = None,
                     block_size: int = 0,
                     strict_pallas: bool = False,
+                    mesh: Optional[jax.sharding.Mesh] = None,
                     ) -> Tuple[jnp.ndarray, Optional[AttnCache]]:
     """Full attention sub-layer (projections + RoPE + attention + out-proj).
 
@@ -348,7 +349,8 @@ def attention_block(x: jnp.ndarray, p: Dict[str, jnp.ndarray],
     into the module-level trace-time log — see ``dispatch_log`` /
     ``fallback_counts``.  ``strict_pallas=True`` turns a requested-but-
     inapplicable fused path from a silent fallback into a loud
-    ``FusedPathUnavailable`` at trace time.
+    ``FusedPathUnavailable`` at trace time.  ``mesh`` (the model's mesh)
+    lets the fused paged kernel run per device under ``shard_map``.
     """
     B, S, d = x.shape
     window = 0 if (is_global and cfg.global_attn_every) else cfg.sliding_window
@@ -396,7 +398,8 @@ def attention_block(x: jnp.ndarray, p: Dict[str, jnp.ndarray],
             out = paged_attention(
                 q, k_cache.reshape(1, B * S_max, Hkv, hd),
                 v_cache.reshape(1, B * S_max, Hkv, hd), table, cl,
-                block_size=bs_slab, softcap=softcap, interpret=interpret)
+                block_size=bs_slab, softcap=softcap, interpret=interpret,
+                mesh=mesh)
         else:
             _record_dispatch(
                 "prefill_continue", fused=False, requested=use_pallas,
@@ -485,7 +488,7 @@ def attention_block(x: jnp.ndarray, p: Dict[str, jnp.ndarray],
             _record_dispatch(branch, fused=True, requested=True)
             out = paged_attention(q, k_cache, v_cache, block_table, cl,
                                   block_size=block_size, softcap=softcap,
-                                  interpret=interpret)
+                                  interpret=interpret, mesh=mesh)
         else:
             _record_dispatch(branch, fused=False, requested=False,
                              reason="use_pallas not requested")

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ParallelConfig, ShapeConfig
 from repro.core.moe_layer import MoEBlockSpec
+from repro.kernels import pallas_interpret
 from repro.models import attention as A
 from repro.models import transformer as T
 from repro.models.layers import (init_embedding, init_mlp, init_norm, mlp,
@@ -81,6 +82,7 @@ class Model:
     init_paged_cache: Callable[..., Any] = None
     input_specs: Callable[..., Any] = None
     moe_spec: Optional[MoEBlockSpec] = None
+    pallas_interpret: bool = False
 
 
 def _dtype_of(cfg: ModelConfig):
@@ -91,6 +93,7 @@ def build_model(cfg: ModelConfig, pcfg: ParallelConfig, *, batch: int,
                 seq_len: int, mesh_shape: MeshShape = MeshShape(),
                 mesh: Optional[jax.sharding.Mesh] = None) -> Model:
     dtype = _dtype_of(cfg)
+    interpret = pallas_interpret()
     Vp = cfg.padded_vocab
     d = cfg.d_model
     b_shards = mesh_shape.batch_shards(batch)
@@ -133,7 +136,7 @@ def build_model(cfg: ModelConfig, pcfg: ParallelConfig, *, batch: int,
             cf_pair=pcfg.moe_cf_pair,
             block_m=pcfg.moe_block_m,
             use_pallas=pcfg.use_pallas,
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret,
             tp_mode=cfg.moe.num_experts < mesh_shape.ep_degree,
             seq_sharded=(seq_len % mesh_shape.ep_degree == 0
                          and mesh_shape.ep_degree > 1))
@@ -224,7 +227,7 @@ def build_model(cfg: ModelConfig, pcfg: ParallelConfig, *, batch: int,
                 valid_mask=valid_mask, block_table=block_table,
                 block_size=block_size, moe_replica_ids=moe_replica_ids,
                 moe_residency_ids=moe_residency_ids,
-                moe_layer_diags=moe_layer_diags)
+                moe_layer_diags=moe_layer_diags, interpret=interpret)
         h = norm(h, params["final_norm"], cfg.norm)
         return h, new_cache, diags
 
@@ -237,7 +240,7 @@ def build_model(cfg: ModelConfig, pcfg: ParallelConfig, *, batch: int,
         h, _, _ = T.run_stack(h, params["encoder"]["stack"], enc_cfg,
                               dataclasses.replace(pcfg),
                               mode="encode", moe_spec=None, mesh=mesh,
-                              causal=False)
+                              causal=False, interpret=interpret)
         return norm(h, params["encoder"]["final_norm"], cfg.norm)
 
     def _embed_tokens(params, tokens, offset=0):
@@ -514,7 +517,7 @@ def build_model(cfg: ModelConfig, pcfg: ParallelConfig, *, batch: int,
                  decode_step=decode_step,
                  init_cache=init_cache, init_paged_cache=init_paged_cache,
                  input_specs=input_specs,
-                 moe_spec=moe_spec)
+                 moe_spec=moe_spec, pallas_interpret=interpret)
 
 
 # ----------------------------------------------------------------------

@@ -88,8 +88,11 @@ def _apply_one_layer(x: jnp.ndarray, p: Dict[str, Any], kind: str,
                      continue_prefill: bool = False,
                      valid_mask=None, block_table=None, block_size: int = 0,
                      moe_replica_ids=None, moe_residency_ids=None,
+                     interpret: bool = False,
                      ) -> Tuple[jnp.ndarray, Any, Dict[str, jnp.ndarray]]:
-    """One layer of any kind. Returns (x, new_cache, diag)."""
+    """One layer of any kind. Returns (x, new_cache, diag).
+    ``interpret`` runs the fused attention kernels in Pallas interpret
+    mode (the model's ``pallas_interpret``)."""
     diag: Dict[str, jnp.ndarray] = {}
     x = constrain(x, mode)
     if kind == "mamba":
@@ -106,10 +109,10 @@ def _apply_one_layer(x: jnp.ndarray, p: Dict[str, Any], kind: str,
         h, p["attn"], cfg, causal=causal, is_global=is_global,
         q_offset=q_offset, cache=cache, cache_len=cache_len,
         attn_chunk=pcfg.attn_chunk, use_pallas=pcfg.use_pallas,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret,
         continue_prefill=continue_prefill,
         block_table=block_table, block_size=block_size,
-        strict_pallas=pcfg.pallas_strict)
+        strict_pallas=pcfg.pallas_strict, mesh=mesh)
     if cfg.post_norm:
         h = norm(h, p["post_norm1"], cfg.norm)
     x = x + h
@@ -197,7 +200,7 @@ def run_stack(x: jnp.ndarray, params: Dict[str, Any], cfg: ModelConfig,
               continue_prefill: bool = False, valid_mask=None,
               block_table=None, block_size: int = 0,
               moe_replica_ids=None, moe_residency_ids=None,
-              moe_layer_diags: bool = False,
+              moe_layer_diags: bool = False, interpret: bool = False,
               ) -> Tuple[jnp.ndarray, Any, Dict[str, jnp.ndarray]]:
     """mode: train | prefill | decode | encode. Returns (x, new_cache, diags).
 
@@ -216,7 +219,8 @@ def run_stack(x: jnp.ndarray, params: Dict[str, Any], cfg: ModelConfig,
             q_offset=q_offset, cache=c, cache_len=cache_len,
             moe_spec=None, mesh=mesh, skew_key=skew_key, causal=causal,
             constrain=constrain, continue_prefill=continue_prefill,
-            block_table=block_table, block_size=block_size)
+            block_table=block_table, block_size=block_size,
+            interpret=interpret)
         new_lead_caches.append(nc)
 
     def step(carry, inp):
@@ -236,7 +240,7 @@ def run_stack(x: jnp.ndarray, params: Dict[str, Any], cfg: ModelConfig,
                 constrain=constrain, continue_prefill=continue_prefill,
                 valid_mask=valid_mask, block_table=block_table,
                 block_size=block_size, moe_replica_ids=moe_replica_ids,
-                moe_residency_ids=moe_residency_ids)
+                moe_residency_ids=moe_residency_ids, interpret=interpret)
             new_caches[f"sub{j}"] = nc
             diags.update({f"{k}": v for k, v in d.items()})
         new_key = (jax.random.fold_in(key, 997) if key is not None else None)

@@ -431,7 +431,8 @@ class ServeEngine:
         # --- sequence-state store (serve/statestore.py): KVOwner for
         # transformer K/V, SlotStateStore for SSM/hybrid recurrent state —
         # the engine talks only to the SequenceStateStore protocol ---
-        self.kv = make_state_store(model, ecfg, s_pad=s_pad, ctx=self._ctx)
+        self.kv = make_state_store(model, ecfg, s_pad=s_pad, ctx=self._ctx,
+                                   mesh=mesh)
         # --- admission/scheduling front (serve/frontend.py) ---
         self.front = AdmissionFront(B)
         self._register_sections()
@@ -1490,6 +1491,7 @@ class ServeEngine:
             "steps": self._step_idx,
             "paged": self._paged,
             "role": self.role,
+            "pallas_interpret": self.model.pallas_interpret,
         }
         if self._paged:
             rep["engine"]["kv_block_size"] = self.ecfg.kv_block_size

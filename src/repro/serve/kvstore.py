@@ -37,7 +37,7 @@ from repro.serve.paging import (NULL_BLOCK, BlockAllocator, blocks_for_tokens,
                                 copy_block, gather_prefix_blocks,
                                 write_chunk_blocks)
 from repro.serve.slots import (discover_batch_axes, discover_seq_axes,
-                               min_kv_capacity, write_slot)
+                               min_kv_capacity, place_cache, write_slot)
 
 
 @dataclass
@@ -141,7 +141,8 @@ class KVOwner:
     over the slab comes from for long-context windowed serving.
     """
 
-    def __init__(self, model, ecfg, *, s_pad: int, ctx: Callable[[], Any]):
+    def __init__(self, model, ecfg, *, s_pad: int, ctx: Callable[[], Any],
+                 mesh=None):
         self.ecfg = ecfg
         self.paged = ecfg.paged
         self.sharing = ecfg.prefix_sharing
@@ -192,15 +193,17 @@ class KVOwner:
             self.block_table = np.full((B, self.blocks_per_slot),
                                        NULL_BLOCK, np.int32)
             self.kv_capacity = s_pad
-            with self._ctx():
-                # the pool/scratch are built over the unclamped cache
-                # (assert_pageable validates full KV axes at s_pad; the
-                # window is enforced by ring_mods + the attention mask,
-                # never by silent truncation)
-                self.pool = model.init_paged_cache(
+            # the pool/scratch are built over the unclamped cache
+            # (assert_pageable validates full KV axes at s_pad; the
+            # window is enforced by ring_mods + the attention mask,
+            # never by silent truncation)
+            self.pool = place_cache(
+                lambda: model.init_paged_cache(
                     self.alloc.num_blocks, bs, s_pad,
-                    seq_axes=self.seq_axes, clamp_window=False)
-                self.scratch = model.init_cache(1, s_pad, False)
+                    seq_axes=self.seq_axes, clamp_window=False),
+                mesh)
+            self.scratch = place_cache(
+                lambda: model.init_cache(1, s_pad, False), mesh)
             ring_mods = self.ring_mods if self.ring else None
             self.write_fn = jax.jit(
                 lambda pool, scratch, bt_row, start, valid_to:
@@ -224,9 +227,10 @@ class KVOwner:
                                                   ecfg.max_seq_len)
             self.kv_capacity = min_kv_capacity(
                 model.init_cache, ecfg.max_seq_len, self.seq_axes)
-            with self._ctx():
-                self.pool = model.init_cache(B, ecfg.max_seq_len)
-                self.scratch = model.init_cache(1, ecfg.max_seq_len)
+            self.pool = place_cache(
+                lambda: model.init_cache(B, ecfg.max_seq_len), mesh)
+            self.scratch = place_cache(
+                lambda: model.init_cache(1, ecfg.max_seq_len), mesh)
             self.write_fn = jax.jit(
                 lambda pool, scratch, slot: write_slot(pool, scratch, slot,
                                                        self.batch_axes))

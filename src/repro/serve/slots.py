@@ -23,6 +23,36 @@ import jax
 import jax.numpy as jnp
 
 
+def place_cache(build: Callable[[], Any], mesh) -> Any:
+    """Build a cache pytree straight onto ``mesh`` so a pool never lands
+    whole on the first device.  K/V leaves (``[..., Hkv, hd]``) are split
+    over kv heads on the ``model`` axis where the head count divides it,
+    the layout the head-sharded attention projections write; every other
+    leaf is replicated explicitly.  (The dry run's ``cache_shardings``
+    may split the KV-length axis instead, which a block-table pool
+    cannot use: its blocks are addressed through the table.)  A missing
+    mesh keeps JAX's default placement.
+
+    Specs are spelled the way the jitted steps hand the pool back (no
+    trailing ``None``, no axis of size 1): jit keys its cache on the
+    spelling, so ``P(.., "model", None)`` in and ``P(.., "model")`` out
+    would compile every step twice."""
+    if mesh is None:
+        return build()
+    from jax.sharding import NamedSharding, PartitionSpec
+    ep = dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
+
+    def sharding(path, leaf):
+        if ep > 1 and jax.tree_util.keystr(path).endswith((".k", ".v")) \
+                and leaf.shape[-2] % ep == 0:
+            return NamedSharding(
+                mesh, PartitionSpec(*[None] * (leaf.ndim - 2), "model"))
+        return NamedSharding(mesh, PartitionSpec())
+    shardings = jax.tree_util.tree_map_with_path(sharding,
+                                                 jax.eval_shape(build))
+    return jax.jit(build, out_shardings=shardings)()
+
+
 def _differing_axes(la, lb) -> list:
     """Axis indices where two shape-evaluated leaves disagree."""
     return [i for i, (x, y) in enumerate(zip(la.shape, lb.shape)) if x != y]

@@ -49,7 +49,7 @@ import jax
 import numpy as np
 
 from repro.serve.slots import (discover_batch_axes, discover_seq_axes,
-                               min_kv_capacity, write_slot)
+                               min_kv_capacity, place_cache, write_slot)
 
 AdmitPlan = Tuple[int, List[int], int, bool]
 
@@ -159,11 +159,10 @@ class SlotStateStore:
     are masked out of the fold itself — ``mamba_block(valid_len=...)``).
     """
 
-    def __init__(self, model, ecfg, *, ctx: Callable[[], Any]):
+    def __init__(self, model, ecfg, *, mesh=None):
         self.ecfg = ecfg
         self.paged = False
         self.sharing = False
-        self._ctx = ctx
         # protocol surface the paged implementation populates
         self.alloc = None
         self.block_table = None
@@ -184,9 +183,10 @@ class SlotStateStore:
         self.kv_capacity = min_kv_capacity(
             model.init_cache, ecfg.max_seq_len, self.seq_axes,
             default=ecfg.max_seq_len)
-        with self._ctx():
-            self.pool = model.init_cache(B, ecfg.max_seq_len)
-            self.scratch = model.init_cache(1, ecfg.max_seq_len)
+        self.pool = place_cache(
+            lambda: model.init_cache(B, ecfg.max_seq_len), mesh)
+        self.scratch = place_cache(
+            lambda: model.init_cache(1, ecfg.max_seq_len), mesh)
         # pristine zero state for begin_prefill resets: jax arrays are
         # immutable, so holding the initial scratch pytree (never fed back
         # through any jitted update) is a zero-copy template
@@ -241,7 +241,8 @@ class SlotStateStore:
         return {"write_slot": self.write_fn._cache_size()}
 
 
-def make_state_store(model, ecfg, *, s_pad: int, ctx: Callable[[], Any]):
+def make_state_store(model, ecfg, *, s_pad: int, ctx: Callable[[], Any],
+                     mesh=None):
     """Pick the state-store implementation for ``model``.
 
     SSM and hybrid families carry recurrent per-sequence state, which has
@@ -257,5 +258,5 @@ def make_state_store(model, ecfg, *, s_pad: int, ctx: Callable[[], Any]):
                 f"{cfg.name} ({cfg.family}) carries fixed-size recurrent "
                 f"state with no KV-length axis to page; serve it from the "
                 f"slotted state pool (EngineConfig.paged=False)")
-        return SlotStateStore(model, ecfg, ctx=ctx)
-    return KVOwner(model, ecfg, s_pad=s_pad, ctx=ctx)
+        return SlotStateStore(model, ecfg, mesh=mesh)
+    return KVOwner(model, ecfg, s_pad=s_pad, ctx=ctx, mesh=mesh)

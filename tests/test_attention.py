@@ -100,9 +100,13 @@ def test_prefill_ring_rotation(S):
 
 
 def test_paged_decode_rejects_binding_window():
-    """The paged decode branch attends window-free; a sliding window that
-    could actually mask something (window < logical range) must raise
-    instead of being silently dropped."""
+    """A binding sliding window (window < logical range) pages through the
+    window ring: single-query decode runs the ring gather, while what the
+    ring cannot serve is rejected by name — a multi-query verify window,
+    and a strictly required fused kernel (the kernel has no ring
+    arithmetic)."""
+    from repro.models.attention import (FusedPathUnavailable,
+                                        dispatch_log, reset_dispatch_log)
     cfg = _swa_cfg(8)
     p = init_attention(jax.random.PRNGKey(0), cfg, jnp.float32)
     B, bs, n_logical = 2, 4, 4                 # L_max = 16 > window = 8
@@ -111,10 +115,22 @@ def test_paged_decode_rejects_binding_window():
     pool = AttnCache(jnp.zeros((1, P, hkv, hd)), jnp.zeros((1, P, hkv, hd)))
     x = jax.random.normal(jax.random.PRNGKey(1), (B, 1, cfg.d_model))
     bt = jnp.ones((B, n_logical), jnp.int32)
-    with pytest.raises(NotImplementedError, match="sliding window"):
+    reset_dispatch_log()
+    y, _ = attention_block(x, p, cfg, q_offset=jnp.zeros((B,), jnp.int32),
+                           cache=pool, cache_len=jnp.ones((B,), jnp.int32),
+                           block_table=bt, block_size=bs)
+    assert y.shape == (B, 1, cfg.d_model)
+    assert [r["branch"] for r in dispatch_log()] == ["decode_ring"]
+    x2 = jax.random.normal(jax.random.PRNGKey(2), (B, 2, cfg.d_model))
+    with pytest.raises(NotImplementedError, match="single-query"):
+        attention_block(x2, p, cfg, q_offset=jnp.zeros((B,), jnp.int32),
+                        cache=pool, cache_len=jnp.full((B,), 2, jnp.int32),
+                        block_table=bt, block_size=bs)
+    with pytest.raises(FusedPathUnavailable, match="no fused kernel"):
         attention_block(x, p, cfg, q_offset=jnp.zeros((B,), jnp.int32),
                         cache=pool, cache_len=jnp.ones((B,), jnp.int32),
-                        block_table=bt, block_size=bs)
+                        block_table=bt, block_size=bs, use_pallas=True,
+                        strict_pallas=True)
     # a window that can never bind (window >= L_max) is dropped exactly
     cfg_wide = _swa_cfg(bs * n_logical)
     y, _ = attention_block(x, p, cfg_wide,

@@ -12,6 +12,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def _run(body: str):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # the child never races for a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
@@ -157,7 +158,6 @@ def test_compressed_psum_grad_agreement():
     import numpy as np, jax, jax.numpy as jnp
     from repro.optim.compress import compressed_psum
     from repro.launch.mesh import make_mesh
-    from repro.core.compat import shard_map
     P = jax.sharding.PartitionSpec
     mesh = make_mesh((8,), ("data",))
     g_global = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
@@ -167,9 +167,9 @@ def test_compressed_psum_grad_agreement():
         out, new_err = compressed_psum(grads, err, jax.random.PRNGKey(1),
                                        axis_name="data")
         return out["w"][None]
-    got = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data", None),
-                                out_specs=P("data", None),
-                                check_vma=False))(g_global)
+    got = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data", None),
+                                    out_specs=P("data", None),
+                                    check_vma=False))(g_global)
     want = np.asarray(g_global).mean(axis=0)
     err = np.abs(np.asarray(got)[0] - want).max()
     scale = np.abs(np.asarray(g_global)).max() / 127

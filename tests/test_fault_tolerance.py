@@ -12,6 +12,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def _train(tmp, steps, fail_at=None, seed=0):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # the child never races for a chip
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     if fail_at is not None:
         env["REPRO_FAIL_AT_STEP"] = str(fail_at)

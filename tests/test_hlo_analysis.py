@@ -5,7 +5,6 @@ import pytest
 
 from repro.launch.hlo_analysis import analyze
 from repro.launch.mesh import make_mesh
-from repro.core.compat import shard_map
 
 
 def test_scan_flops_multiplied_by_trip_count():
@@ -35,8 +34,8 @@ def test_collectives_inside_scan_counted_per_iteration():
             return jax.lax.psum(c @ wi, "m"), None
         return jax.lax.scan(step, x, w)[0]
 
-    g = shard_map(f, mesh=mesh, in_specs=P(None, None),
-                      out_specs=P(None, None), check_vma=False)
+    g = jax.shard_map(f, mesh=mesh, in_specs=P(None, None),
+                          out_specs=P(None, None), check_vma=False)
     r = analyze(jax.jit(g).lower(x).compile().as_text())
     assert r["collective_counts"]["all-reduce"] == 8
 

@@ -58,6 +58,20 @@ def test_moe_gmm_matches_grouped_ffn_ref():
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
+@pytest.mark.parametrize("d,f,want", [
+    (2048, 1408, 1408),      # Qwen1.5-MoE: all of f in one ~38 MB tile
+    (4096, 14336, 1024),     # Mixtral: largest 128-multiple under budget
+])
+def test_pick_block_f_within_vmem_budget(d, f, want):
+    from repro.kernels.moe_gmm.moe_gmm import (VMEM_TILE_BUDGET,
+                                               pick_block_f, tile_vmem_bytes)
+    bf = pick_block_f(d, f, block_m=128, itemsize=2, gated=True)
+    assert bf == want and f % bf == 0
+    assert tile_vmem_bytes(128, bf, d, 2, True) <= VMEM_TILE_BUDGET
+    with pytest.raises(ValueError, match="no block_f"):
+        pick_block_f(d, f, block_m=128, itemsize=2, gated=True, budget=2 ** 20)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("shape", [

@@ -28,6 +28,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def _run(body: str):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # the child never races for a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
@@ -40,7 +41,6 @@ def test_fetch_chunked_padding_parity():
     out = _run("""
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.core.compat import shard_map
     from repro.core.prefetch import fetch_foreign_weights
     from repro.core.topology import make_topology
 
@@ -67,8 +67,8 @@ def test_fetch_chunked_padding_parity():
                 return fetch_foreign_weights(
                     w_local, jnp.asarray(fids), me, topo,
                     axis_name="model", fetch_chunk=chunk)
-            f = shard_map(body, mesh=mesh, in_specs=P("model"),
-                          out_specs=P("model"))
+            f = jax.shard_map(body, mesh=mesh, in_specs=P("model"),
+                              out_specs=P("model"))
             with mesh:
                 return np.asarray(jax.jit(f)(jnp.asarray(w)))
 

@@ -85,6 +85,18 @@ def test_q_threshold_stops_small_moves():
     assert int(diag.moved) == 0
 
 
+def test_device_hardware_peaks_by_kind():
+    """Eq. 4's peaks come from the running device's row; the CPU backend
+    models a v5e, and a TPU kind without a row is an error."""
+    from types import SimpleNamespace
+    from repro.core.qthreshold import PEAKS, V5E, device_hardware
+    assert device_hardware() is V5E                  # tests run on the CPU
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_hardware(v5e) is PEAKS["TPU v5 lite"] is V5E
+    with pytest.raises(KeyError, match="TPU v9"):
+        device_hardware(SimpleNamespace(platform="tpu", device_kind="TPU v9"))
+
+
 def test_foreign_slot_budget():
     """No destination hosts more than K distinct non-resident experts."""
     topo = make_topology(4, 8)

@@ -302,11 +302,22 @@ def test_moe_engine_diagnostics_and_skew_keys():
 
 
 def test_engine_rejects_unsupported_families():
+    """SSM state is served from the slotted pool; asking to page it is
+    rejected by ``make_state_store`` with the reason (recurrent state has
+    no KV-length axis), and encoder-decoder models are still refused."""
     cfg = get_config("mamba2-2.7b").reduced()
     model, params = _model(cfg, 1, 8)
-    with pytest.raises(NotImplementedError):
+    with pytest.raises(ValueError, match="recurrent state"):
         _engine(cfg, model, params, slots=1, prompt_len=8, max_new=2,
-                chunk=4)
+                chunk=4, paged=True)
+    eng = _engine(cfg, model, params, slots=1, prompt_len=8, max_new=2,
+                  chunk=4)
+    assert eng.kv.stats()["kind"] == "slot"
+    cfg_ed = get_config("whisper-large-v3").reduced()
+    model_ed, params_ed = _model(cfg_ed, 1, 8)
+    with pytest.raises(NotImplementedError, match="decoder-only"):
+        _engine(cfg_ed, model_ed, params_ed, slots=1, prompt_len=8,
+                max_new=2, chunk=4)
 
 
 # ----------------------------------------------------------------------

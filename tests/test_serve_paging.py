@@ -229,24 +229,32 @@ def test_paged_outlives_slab_at_equal_memory():
 
 def test_paged_rejects_window_clamped_cache():
     """A model whose sliding window binds below the padded pool length
-    cannot be paged (the paged decode path is window-free) —
-    engine_config_for rejects the shapes with an actionable error, and a
+    is paged as a window ring of round_up(window, block_size) positions.
+    engine_config_for rejects a prefill chunk wider than the ring, and a
     hand-built EngineConfig that sneaks past it is rejected at engine
-    construction with a clear window error (not just the late structural
-    leaf rejection)."""
+    construction by the ring's named blockers (every blocker at once)."""
     from repro.serve import EngineConfig
     cfg = TINY.replace(sliding_window=8)
     model, params = _model(cfg, 1, 16)
-    # max_seq_len 16+8=24 > window 8 -> leaf clamped -> not pageable
-    with pytest.raises(ValueError, match="sliding window"):
-        engine_config_for(cfg, max_slots=1, prompt_len=8,
-                          max_new_tokens=16, prefill_chunk=8,
+    # max_seq_len 16+8=24 > window 8 -> ring of 8 positions
+    with pytest.raises(ValueError, match="sliding-window ring"):
+        engine_config_for(cfg, max_slots=1, prompt_len=16,
+                          max_new_tokens=8, prefill_chunk=16,
                           paged=True, kv_block_size=4)
-    with pytest.raises(ValueError, match="window-free"):
+    ring = ServeEngine(model, params,
+                       EngineConfig(max_slots=1, max_seq_len=24,
+                                    prefill_chunk=8, paged=True,
+                                    kv_block_size=4))
+    assert ring.kv.stats()["window_ring"] and ring.blocks_per_slot == 2
+    with pytest.raises(ValueError, match="window ring buffer") as err:
         ServeEngine(model, params,
                     EngineConfig(max_slots=1, max_seq_len=24,
-                                 prefill_chunk=8, paged=True,
-                                 kv_block_size=4))
+                                 prefill_chunk=16, paged=True,
+                                 kv_block_size=4, speculative_k=2,
+                                 fused_paged_attention=True))
+    for blocker in ("prefill_chunk 16 > ring 8", "single-query",
+                    "no ring arithmetic"):
+        assert blocker in str(err.value)
     # a window the padded pool fits inside never binds: accepted, and the
     # engine still serves token streams (window-free == exact there)
     cfg_wide = TINY.replace(sliding_window=64)
@@ -257,14 +265,18 @@ def test_paged_rejects_window_clamped_cache():
                                    kv_block_size=4))
     assert eng.blocks_per_slot == 6
     # prefix sharing pads one extra chunk: shapes that fit a window
-    # without sharing are rejected with it, up front
+    # without sharing make the window bind with it, and the ring then
+    # rejects sharing by name
     cfg64 = TINY.replace(sliding_window=64)
-    engine_config_for(cfg64, max_slots=1, prompt_len=56, max_new_tokens=8,
-                      prefill_chunk=8, paged=True, kv_block_size=4)
-    with pytest.raises(ValueError, match="extra prefill chunk"):
-        engine_config_for(cfg64, max_slots=1, prompt_len=56,
-                          max_new_tokens=8, prefill_chunk=8, paged=True,
-                          kv_block_size=4, prefix_sharing=True)
+    model64, params64 = _model(cfg64, 1, 16)
+    ServeEngine(model64, params64, engine_config_for(
+        cfg64, max_slots=1, prompt_len=56, max_new_tokens=8,
+        prefill_chunk=8, paged=True, kv_block_size=4))
+    with pytest.raises(ValueError, match="absolute sequence length"):
+        ServeEngine(model64, params64, engine_config_for(
+            cfg64, max_slots=1, prompt_len=56, max_new_tokens=8,
+            prefill_chunk=8, paged=True, kv_block_size=4,
+            prefix_sharing=True))
 
 
 def test_paged_mixed_lengths_decode_together():

@@ -169,6 +169,7 @@ def test_metrics_scalar_only_has_no_load_balance():
 # ----------------------------------------------------------------------
 def _run(body: str):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # the child never races for a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
@@ -307,3 +308,52 @@ def test_engine_rejects_replica_slot_mismatch():
     else:
         raise AssertionError("mismatched replica slots were accepted")
     """)
+
+
+def test_sharded_serving_path_fused_attention():
+    """The serving CLI's build path on a 4-rank mesh: parameters are
+    initialised on their shards (experts over ``model``), the paged pool
+    splits its kv heads the same way, the fused paged kernel runs per
+    device under ``shard_map``, every jit entry compiles once, and the
+    greedy streams match the reference block-table gather."""
+    out = _run("""
+    import jax
+    from repro.launch.serve import build_parser, build_serving_engine
+    from repro.serve import poisson_requests
+
+    def run(*extra):
+        args = build_parser().parse_args([
+            "--arch", "qwen15-moe-a27b", "--reduced", "--model-par", "4",
+            "--batch", "4", "--prompt-len", "16", "--gen", "4", "--paged",
+            "--kv-block-size", "8", "--prefill-chunk", "8", "--skew", "0.9",
+            "--q-tokens", "1", "--fused-moe", *extra])
+        cfg, eng = build_serving_engine(args)
+        outs, finish = {}, eng._finish
+
+        def capture(st, now):
+            outs[st.req.rid] = list(st.output)
+            finish(st, now)
+        eng._finish = capture
+        eng.warmup()
+        rep = eng.run(poisson_requests(4, rate=0.0, vocab_size=cfg.vocab_size,
+                                       prompt_len=16, max_new_tokens=4))
+        return eng, rep, outs
+
+    eng, rep, fused = run("--fused-attention")
+    leaves = jax.tree_util.tree_leaves_with_path(eng.params)
+    w_in = [l for p, l in leaves if "moe" in jax.tree_util.keystr(p)
+            and jax.tree_util.keystr(p).endswith("['w_in']")][0]
+    assert w_in.addressable_shards[0].data.shape[-3] \\
+        == w_in.shape[-3] // 4, w_in.sharding
+    for p, l in jax.tree_util.tree_leaves_with_path(eng.pool):
+        assert l.addressable_shards[0].data.shape[-2] == l.shape[-2] // 4
+    assert all(n == 1 for n in rep["jit_entries"].values()), rep["jit_entries"]
+    assert rep["recompiled_after_warmup"] is False
+    assert rep["attention_fallbacks"] == {}
+    assert rep["attention_dispatch"]["decode"]["fused"]
+    _, rep_ref, ref = run()
+    assert not rep_ref["attention_dispatch"]["decode"]["fused"]
+    assert fused == ref and len(fused) == 4, (fused, ref)
+    print("OK")
+    """)
+    assert "OK" in out

@@ -26,6 +26,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def _run(body: str):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # the child never races for a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
