@@ -64,16 +64,14 @@ machine-readable ``BENCH_serve.json``:
   of actual length (constant bytes), the fused kernel touches only the
   valid blocks (bytes scale with the active length);
 * ``phases`` — per-phase serving breakdown (prefill / prefix-tail /
-  decode / verify tokens-per-second and analytic KV bytes touched) with
-  the unified fused path on vs the reference gather, at equal config:
-  a long-context (2k-prompt, window disabled) prefix-sharing cell and a
+  decode / verify tokens and tokens-per-second) with the unified fused
+  path on vs the reference gather, at equal config: a long-context
+  (2k-prompt, window disabled) prefix-sharing cell and a
   speculative-verify cell.  The fused cells assert that no hot phase
   dispatches the logical gather (``attention_dispatch`` is fused on
   every traced branch, ``attention_fallbacks`` empty).  Off-TPU the
-  fused kernels run in interpret mode, so wall tokens/s are reported
-  but the comparison carries on the analytic bytes and the clearly
-  labeled ``modeled_roofline_tok_s`` (bytes / v5e HBM bandwidth);
-  on TPU the wall columns are real.
+  fused kernels run in interpret mode, so the wall columns time the
+  interpreter, not the kernels.
 
   PYTHONPATH=src python benchmarks/serve_load.py [--out BENCH_serve.json]
 """
@@ -970,22 +968,16 @@ def _phase_engine(*, fused: bool, prompt_len: int, gen: int, chunk: int,
 
 
 def phases_breakdown():
-    """Per-phase tok/s + analytic KV bytes, fused vs gather (docstring top).
+    """Per-phase tokens and tok/s, fused vs gather (docstring top).
 
     Cell pairs (identical workload, greedy, token counts cross-checked):
 
     * ``long_prefill`` — 2048-token prompts sharing a 1024-token prefix,
       prefix sharing on: exercises prefill, prefix-tail resume, and plain
-      decode.  The reference ``chunked_attention`` scans the whole
-      [1, s_pad] slab every chunk; the fused q-tiled kernel's causal
-      pruning stops at ``q_offset + chunk``, so its bytes grow with the
-      filled prefix instead of the pool size.
+      decode.
     * ``spec_verify`` — short repetitive prompts, speculative k=4:
-      exercises the [B, k+1] verify phase, where the reference gather
-      materializes every row's full logical view per step.
+      exercises the [B, k+1] verify phase.
     """
-    from repro.core.qthreshold import V5E
-
     interpret = pallas_interpret()
     cells = []
 
@@ -1024,16 +1016,8 @@ def phases_breakdown():
             engine.run(warm)
         rep = engine.run(reqs)
         wall_s = time.perf_counter() - t0
-        phases = {}
-        for name, ph in rep.get("phases", {}).items():
-            ph = dict(ph)
-            # bytes-roofline model: phase time if KV traffic were the
-            # bottleneck at v5e HBM bandwidth — the TPU-relevant contrast
-            # when the wall columns run the kernel in interpret mode
-            ph["modeled_roofline_tok_s"] = (
-                ph["tokens"] / (ph["kv_bytes_touched"] / V5E.hbm_bw)
-                if ph["kv_bytes_touched"] else None)
-            phases[name] = ph
+        phases = {name: dict(ph)
+                  for name, ph in rep.get("phases", {}).items()}
         cell = {
             "workload": workload, "fused": fused,
             "prompt_len": prompt_len, "gen": gen,
@@ -1042,8 +1026,6 @@ def phases_breakdown():
             "total_new_tokens": rep["total_new_tokens"],
             "e2e_wall_s": wall_s,
             "e2e_tok_s_wall": rep["total_new_tokens"] / wall_s,
-            "kv_bytes_total": sum(ph["kv_bytes_touched"]
-                                  for ph in phases.values()),
             "phases": phases,
             "attention_dispatch": rep.get("attention_dispatch", {}),
             "attention_fallbacks": rep.get("attention_fallbacks", {}),
@@ -1058,9 +1040,8 @@ def phases_breakdown():
         cells.append(cell)
         for name, ph in sorted(phases.items()):
             print(f"[bench] phases {workload:12s} fused={str(fused):5s} "
-                  f"{name:11s} tok/s={ph['tokens_per_s']:9.1f} "
-                  f"bytes/token={ph['kv_bytes_per_token']:10.0f} "
-                  f"roofline={ph['modeled_roofline_tok_s'] or 0:12.0f}")
+                  f"{name:11s} tokens={ph['tokens']:6d} "
+                  f"tok/s={ph['tokens_per_s']:9.1f}")
         return cell
 
     # long-context: 2048-token prompts; s_pad = 2048 + 256 (round-up) +
@@ -1086,20 +1067,14 @@ def phases_breakdown():
         summary[w] = {
             "tokens_identical": True,
             "hot_phase": hot,
-            "bytes_ratio_gather_over_fused":
-                g["phases"][hot]["kv_bytes_touched"]
-                / f["phases"][hot]["kv_bytes_touched"],
+            "hot_phase_tok_s_gather": g["phases"][hot]["tokens_per_s"],
+            "hot_phase_tok_s_fused": f["phases"][hot]["tokens_per_s"],
             "e2e_tok_s_wall_gather": g["e2e_tok_s_wall"],
             "e2e_tok_s_wall_fused": f["e2e_tok_s_wall"],
-            "e2e_bytes_gather": g["kv_bytes_total"],
-            "e2e_bytes_fused": f["kv_bytes_total"],
-            "e2e_improves_modeled":
-                f["kv_bytes_total"] < g["kv_bytes_total"],
         }
-        print(f"[bench] phases headline {w}: {hot} bytes ratio "
-              f"{summary[w]['bytes_ratio_gather_over_fused']:.2f}x, "
-              f"e2e bytes {g['kv_bytes_total']} -> {f['kv_bytes_total']} "
-              f"(modeled win: {summary[w]['e2e_improves_modeled']})")
+        print(f"[bench] phases headline {w}: {hot} tok/s "
+              f"{summary[w]['hot_phase_tok_s_gather']:.1f} (gather) vs "
+              f"{summary[w]['hot_phase_tok_s_fused']:.1f} (fused)")
     return {"fused_interpret_mode": interpret, "cells": cells,
             "summary": summary}
 

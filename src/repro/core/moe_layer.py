@@ -178,116 +178,125 @@ def _moe_forward_local(x_rep: jnp.ndarray, params: Dict[str, jnp.ndarray],
                                                t_slice, axis=0)
 
     # --- step 1: routing ------------------------------------------------
-    if skew_key is not None and moe.router_skew > 0.0:
-        key = jax.random.fold_in(skew_key, me)
-        r_out = R.route_skewed(key, t_slice, top_k=k,
-                               num_experts=moe.num_experts,
-                               padded_experts=Ep, alpha=moe.router_skew,
-                               n_hot=moe.router_skew_experts)
-    else:
-        r_out = R.route_topk(x_slice, params["router"], top_k=k,
-                             num_real_experts=moe.num_experts)
-    # mark padding tokens with the sentinel expert id Ep (never scheduled);
-    # valid_rep additionally masks caller-declared dead tokens (inactive
-    # decode slots, prompt-chunk padding) out of routing and capacity
-    tok_idx = me * t_slice + jnp.arange(t_slice)
-    valid_tok = tok_idx < n_valid
-    if valid_rep is not None:
-        v_slice = valid_rep if spec.seq_sharded else \
-            jax.lax.dynamic_slice_in_dim(valid_rep, me * t_slice, t_slice,
-                                         axis=0)
-        valid_tok = valid_tok & v_slice
-    assign = jnp.where(valid_tok[:, None], r_out.assign, Ep)
-    counts = jnp.zeros((Ep,), jnp.int32).at[assign.reshape(-1)].add(
-        1, mode="drop")
+    with jax.named_scope("router"):
+        if skew_key is not None and moe.router_skew > 0.0:
+            key = jax.random.fold_in(skew_key, me)
+            r_out = R.route_skewed(key, t_slice, top_k=k,
+                                   num_experts=moe.num_experts,
+                                   padded_experts=Ep, alpha=moe.router_skew,
+                                   n_hot=moe.router_skew_experts)
+        else:
+            r_out = R.route_topk(x_slice, params["router"], top_k=k,
+                                 num_real_experts=moe.num_experts)
+        # mark padding tokens with the sentinel expert id Ep (never scheduled);
+        # valid_rep additionally masks caller-declared dead tokens (inactive
+        # decode slots, prompt-chunk padding) out of routing and capacity
+        tok_idx = me * t_slice + jnp.arange(t_slice)
+        valid_tok = tok_idx < n_valid
+        if valid_rep is not None:
+            v_slice = valid_rep if spec.seq_sharded else \
+                jax.lax.dynamic_slice_in_dim(valid_rep, me * t_slice, t_slice,
+                                             axis=0)
+            valid_tok = valid_tok & v_slice
+        assign = jnp.where(valid_tok[:, None], r_out.assign, Ep)
+        counts = jnp.zeros((Ep,), jnp.int32).at[assign.reshape(-1)].add(
+            1, mode="drop")
 
     # --- step 2: metadata exchange (~G*Ep*4 bytes on the wire) -----------
-    m_all = jax.lax.all_gather(counts, spec.ep_axis, axis=0)        # [G, Ep]
+    with jax.named_scope("schedule"):
+        m_all = jax.lax.all_gather(counts, spec.ep_axis, axis=0)  # [G, Ep]
 
-    # --- step 3: replicated deterministic scheduling ----------------------
-    extra_local = None
-    rep_ids_me = None
-    if R_slots and replica_ids is not None:
-        # ranks holding a replica of e count as local destinations for e
-        extra_local = D.replica_slot_map(replica_ids, Ep) >= 0  # [G, Ep]
-        rep_ids_me = jnp.take(replica_ids, me, axis=0)          # [R]
-    non_local = None
-    if residency_ids is not None:
-        # tiered residency: statically-placed experts swapped out of HBM
-        # stop counting as free destinations for the rebalancer
-        non_local = prefetch.residency_non_local(residency_ids, topo)
-    S, sdiag = SCH.schedule(m_all, topo, policy=moe.policy, q=spec.q,
-                            c_pair=spec.c_pair,
-                            num_foreign_slots=moe.num_foreign_slots,
-                            extra_local=extra_local, non_local=non_local)
+        # --- step 3: replicated deterministic scheduling ------------------
+        extra_local = None
+        rep_ids_me = None
+        if R_slots and replica_ids is not None:
+            # ranks holding a replica of e count as local destinations for e
+            extra_local = D.replica_slot_map(replica_ids, Ep) >= 0  # [G, Ep]
+            rep_ids_me = jnp.take(replica_ids, me, axis=0)          # [R]
+        non_local = None
+        if residency_ids is not None:
+            # tiered residency: statically-placed experts swapped out of HBM
+            # stop counting as free destinations for the rebalancer
+            non_local = prefetch.residency_non_local(residency_ids, topo)
+        S, sdiag = SCH.schedule(m_all, topo, policy=moe.policy, q=spec.q,
+                                c_pair=spec.c_pair,
+                                num_foreign_slots=moe.num_foreign_slots,
+                                extra_local=extra_local, non_local=non_local)
 
     # --- step 4: scatter ---------------------------------------------------
-    layout = D.build_layout(S, assign, me, topo, c_pair=spec.c_pair,
-                            c_total=spec.c_total,
-                            num_foreign_slots=moe.num_foreign_slots,
-                            block_m=spec.block_m,
-                            num_replica_slots=R_slots,
-                            replica_ids_me=rep_ids_me)
-    x_units = jnp.repeat(x_slice, k, axis=0)                # token-major, k-minor
-    grouped = D.dispatch(x_units, layout, axis_name=spec.ep_axis,
-                         num_ranks=G, c_pair=spec.c_pair,
-                         c_total=spec.c_total)
+    with jax.named_scope("dispatch"):
+        layout = D.build_layout(S, assign, me, topo, c_pair=spec.c_pair,
+                                c_total=spec.c_total,
+                                num_foreign_slots=moe.num_foreign_slots,
+                                block_m=spec.block_m,
+                                num_replica_slots=R_slots,
+                                replica_ids_me=rep_ids_me)
+        x_units = jnp.repeat(x_slice, k, axis=0)    # token-major, k-minor
+        grouped = D.dispatch(x_units, layout, axis_name=spec.ep_axis,
+                             num_ranks=G, c_pair=spec.c_pair,
+                             c_total=spec.c_total)
 
     # --- step 5: expert processing + async weight fetch --------------------
-    w_in, w_out = params["w_in"], params["w_out"]           # local shards [epr,...]
-    w_gate = params.get("w_gate")
-    # replica-slot weight rows for this rank ([R, ...] shards, zeros if empty)
-    w_rep = {name: params.get(name) for name in
-             ("w_rep_in", "w_rep_out", "w_rep_gate")}
+    with jax.named_scope("expert_weights"):
+        w_in, w_out = params["w_in"], params["w_out"]   # local [epr, ...]
+        w_gate = params.get("w_gate")
+        # replica-slot weight rows for this rank ([R, ...] shards, zeros
+        # if empty)
+        w_rep = {name: params.get(name) for name in
+                 ("w_rep_in", "w_rep_out", "w_rep_gate")}
 
-    def with_replicas(w, rep_name):
-        wr = w_rep[rep_name]
-        return w if wr is None else jnp.concatenate(
-            [w, wr.astype(w.dtype)], axis=0)
-    if moe.policy == "even_split":
-        # full replication (paper's Even-Split): gather all experts; the map
-        # covers every group row — local, replica, and foreign alike
-        def per_group(w):
-            w_all = prefetch.gather_all_experts(w, axis_name=spec.ep_axis)
-            rows = _expert_row_map(topo)
-            ge = jnp.minimum(jnp.maximum(layout.group_expert, 0), Ep - 1)
-            return w_all[jnp.asarray(rows)[ge]]
-        w_in_full, w_out_full = per_group(w_in), per_group(w_out)
-        w_gate_full = per_group(w_gate) if w_gate is not None else None
-    elif moe.num_foreign_slots > 0:
-        fids_all = prefetch.all_foreign_ids(S, topo, moe.num_foreign_slots,
-                                            replica_ids=replica_ids
-                                            if R_slots else None)
+        def with_replicas(w, rep_name):
+            wr = w_rep[rep_name]
+            return w if wr is None else jnp.concatenate(
+                [w, wr.astype(w.dtype)], axis=0)
+        if moe.policy == "even_split":
+            # full replication (paper's Even-Split): gather all experts;
+            # the map covers every group row — local, replica, and foreign
+            # alike
+            def per_group(w):
+                w_all = prefetch.gather_all_experts(w, axis_name=spec.ep_axis)
+                rows = _expert_row_map(topo)
+                ge = jnp.minimum(jnp.maximum(layout.group_expert, 0), Ep - 1)
+                return w_all[jnp.asarray(rows)[ge]]
+            w_in_full, w_out_full = per_group(w_in), per_group(w_out)
+            w_gate_full = per_group(w_gate) if w_gate is not None else None
+        elif moe.num_foreign_slots > 0:
+            fids_all = prefetch.all_foreign_ids(S, topo, moe.num_foreign_slots,
+                                                replica_ids=replica_ids
+                                                if R_slots else None)
 
-        def fetch(w, rep_name):
-            wf = prefetch.fetch_foreign_weights(
-                w, fids_all, me, topo, axis_name=spec.ep_axis,
-                fetch_chunk=spec.fetch_chunk)
-            return jnp.concatenate([with_replicas(w, rep_name),
-                                    wf.astype(w.dtype)], axis=0)
-        w_in_full = fetch(w_in, "w_rep_in")
-        w_out_full = fetch(w_out, "w_rep_out")
-        w_gate_full = fetch(w_gate, "w_rep_gate") \
-            if w_gate is not None else None
-    else:
-        w_in_full = with_replicas(w_in, "w_rep_in")
-        w_out_full = with_replicas(w_out, "w_rep_out")
-        w_gate_full = (with_replicas(w_gate, "w_rep_gate")
-                       if w_gate is not None else None)
+            def fetch(w, rep_name):
+                wf = prefetch.fetch_foreign_weights(
+                    w, fids_all, me, topo, axis_name=spec.ep_axis,
+                    fetch_chunk=spec.fetch_chunk)
+                return jnp.concatenate([with_replicas(w, rep_name),
+                                        wf.astype(w.dtype)], axis=0)
+            w_in_full = fetch(w_in, "w_rep_in")
+            w_out_full = fetch(w_out, "w_rep_out")
+            w_gate_full = fetch(w_gate, "w_rep_gate") \
+                if w_gate is not None else None
+        else:
+            w_in_full = with_replicas(w_in, "w_rep_in")
+            w_out_full = with_replicas(w_out, "w_rep_out")
+            w_gate_full = (with_replicas(w_gate, "w_rep_gate")
+                           if w_gate is not None else None)
 
-    sizes_padded = D.round_up_j(layout.group_sizes, spec.block_m)
-    out_grouped = grouped_ffn(grouped, w_in_full, w_out_full, sizes_padded,
-                              w_gate=w_gate_full, act=spec.act,
-                              use_pallas=spec.use_pallas,
-                              interpret=spec.interpret,
-                              block_m=spec.block_m)
+    with jax.named_scope("expert_ffn"):
+        sizes_padded = D.round_up_j(layout.group_sizes, spec.block_m)
+        out_grouped = grouped_ffn(grouped, w_in_full, w_out_full, sizes_padded,
+                                  w_gate=w_gate_full, act=spec.act,
+                                  use_pallas=spec.use_pallas,
+                                  interpret=spec.interpret,
+                                  block_m=spec.block_m)
 
     # --- step 6: gather + combine ------------------------------------------
-    y_slice = D.combine(out_grouped, layout, axis_name=spec.ep_axis,
-                        num_ranks=G, c_pair=spec.c_pair,
-                        gates=r_out.gates, top_k=k)
-    y_rep = (y_slice if spec.seq_sharded
-             else jax.lax.all_gather(y_slice, spec.ep_axis, axis=0, tiled=True))
+    with jax.named_scope("combine"):
+        y_slice = D.combine(out_grouped, layout, axis_name=spec.ep_axis,
+                            num_ranks=G, c_pair=spec.c_pair,
+                            gates=r_out.gates, top_k=k)
+        y_rep = (y_slice if spec.seq_sharded
+                 else jax.lax.all_gather(y_slice, spec.ep_axis, axis=0,
+                                         tiled=True))
 
     t_g = S.sum(axis=(0, 1)).astype(jnp.float32)
     # drops are per-rank quantities; sum them so the reported diagnostic is
@@ -356,35 +365,43 @@ def tp_moe_block(x: jnp.ndarray, params: Dict[str, jnp.ndarray], *,
         B_loc = xb.shape[0]
         T = B_loc * S_len
         flat = xb.reshape(T, d)
-        if key is not None and spec.moe.router_skew > 0.0:
-            r = R.route_skewed(key, T, top_k=k, num_experts=E,
-                               padded_experts=E, alpha=spec.moe.router_skew,
-                               n_hot=spec.moe.router_skew_experts)
-        else:
-            r = R.route_topk(flat, p_router, top_k=k, num_real_experts=E)
+        with jax.named_scope("router"):
+            if key is not None and spec.moe.router_skew > 0.0:
+                r = R.route_skewed(key, T, top_k=k, num_experts=E,
+                                   padded_experts=E,
+                                   alpha=spec.moe.router_skew,
+                                   n_hot=spec.moe.router_skew_experts)
+            else:
+                r = R.route_topk(flat, p_router, top_k=k,
+                                 num_real_experts=E)
         ue = r.assign.reshape(-1)
         U = ue.shape[0]
-        # block-aligned grouped buffer (exact: capacity covers all units)
-        sizes = jnp.zeros((E,), jnp.int32).at[ue].add(1)
-        padded = D.round_up_j(sizes, bm)
-        offsets = jnp.concatenate(
-            [jnp.zeros((1,), jnp.int32), jnp.cumsum(padded)[:-1]])
-        order = jnp.argsort(ue, stable=True)
-        start = jnp.concatenate(
-            [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)[:-1]])
-        r_sorted = jnp.arange(U, dtype=jnp.int32) - start[ue[order]]
-        rank_in_e = jnp.zeros((U,), jnp.int32).at[order].set(r_sorted)
-        row = offsets[ue] + rank_in_e
-        m_pad = round_up(U, bm) + E * bm
-        x_units = jnp.repeat(flat, k, axis=0)
-        x_buf = jnp.zeros((m_pad, d), flat.dtype).at[row].set(x_units)
-        y_buf = grouped_ffn(x_buf, w_in, w_out, padded, w_gate=w_gate,
-                            act=spec.act, use_pallas=spec.use_pallas,
-                            interpret=spec.interpret, block_m=bm)
-        y_buf = jax.lax.psum(y_buf, spec.ep_axis)          # row-parallel
-        y_units = y_buf[row]
-        y = (y_units.reshape(T, k, d)
-             * r.gates.reshape(T, k, 1).astype(y_units.dtype)).sum(axis=1)
+        with jax.named_scope("dispatch"):
+            # block-aligned grouped buffer (exact: capacity covers all
+            # units)
+            sizes = jnp.zeros((E,), jnp.int32).at[ue].add(1)
+            padded = D.round_up_j(sizes, bm)
+            offsets = jnp.concatenate(
+                [jnp.zeros((1,), jnp.int32), jnp.cumsum(padded)[:-1]])
+            order = jnp.argsort(ue, stable=True)
+            start = jnp.concatenate(
+                [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)[:-1]])
+            r_sorted = jnp.arange(U, dtype=jnp.int32) - start[ue[order]]
+            rank_in_e = jnp.zeros((U,), jnp.int32).at[order].set(r_sorted)
+            row = offsets[ue] + rank_in_e
+            m_pad = round_up(U, bm) + E * bm
+            x_units = jnp.repeat(flat, k, axis=0)
+            x_buf = jnp.zeros((m_pad, d), flat.dtype).at[row].set(x_units)
+        with jax.named_scope("expert_ffn"):
+            y_buf = grouped_ffn(x_buf, w_in, w_out, padded, w_gate=w_gate,
+                                act=spec.act, use_pallas=spec.use_pallas,
+                                interpret=spec.interpret, block_m=bm)
+        with jax.named_scope("combine"):
+            y_buf = jax.lax.psum(y_buf, spec.ep_axis)      # row-parallel
+            y_units = y_buf[row]
+            y = (y_units.reshape(T, k, d)
+                 * r.gates.reshape(T, k, 1).astype(y_units.dtype)
+                 ).sum(axis=1)
         zero = jnp.zeros((1,), jnp.float32)
         diag = {"aux_loss": r.aux_loss[None], "send_drops": zero,
                 "dest_drops": zero, "sched_iters": zero, "moved_units": zero,

@@ -100,6 +100,7 @@ def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, block_q, hd),
                                lambda bh, qi, kj: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype),
+        name="flash_attention",
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),

@@ -162,6 +162,7 @@ def moe_gmm(x: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray,
     fn = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, d), x.dtype),
+        name="moe_gmm",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=(int(vmem * 1.25)

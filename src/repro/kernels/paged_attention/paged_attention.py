@@ -211,6 +211,7 @@ def paged_attention_kernel(q: jnp.ndarray, k_pool: jnp.ndarray,
                           q_blk=q_tile, rep=rep),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, QR, hd), q.dtype),
+        name="paged_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),

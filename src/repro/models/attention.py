@@ -301,6 +301,7 @@ class AttnCache(NamedTuple):
     v: jnp.ndarray
 
 
+@jax.named_scope("attention")
 def attention_block(x: jnp.ndarray, p: Dict[str, jnp.ndarray],
                     cfg: ModelConfig, *, causal: bool = True,
                     is_global: bool = True, q_offset: int = 0,

@@ -228,7 +228,8 @@ def build_model(cfg: ModelConfig, pcfg: ParallelConfig, *, batch: int,
                 block_size=block_size, moe_replica_ids=moe_replica_ids,
                 moe_residency_ids=moe_residency_ids,
                 moe_layer_diags=moe_layer_diags, interpret=interpret)
-        h = norm(h, params["final_norm"], cfg.norm)
+        with jax.named_scope("lm_head"):
+            h = norm(h, params["final_norm"], cfg.norm)
         return h, new_cache, diags
 
     def _encode(params, frames):
@@ -401,9 +402,10 @@ def build_model(cfg: ModelConfig, pcfg: ParallelConfig, *, batch: int,
             hl = h[jnp.arange(Bc), idx]
         else:
             hl = jax.lax.dynamic_index_in_dim(h, idx, axis=1, keepdims=False)
-        logits = logits_head(hl, _vocab_w(params),
-                             real_vocab=cfg.vocab_size,
-                             softcap=cfg.final_logit_softcap)
+        with jax.named_scope("lm_head"):
+            logits = logits_head(hl, _vocab_w(params),
+                                 real_vocab=cfg.vocab_size,
+                                 softcap=cfg.final_logit_softcap)
         out = dict(caches)
         out["stack"] = new_stack
         return logits, out, new_pos, diags
@@ -482,15 +484,17 @@ def build_model(cfg: ModelConfig, pcfg: ParallelConfig, *, batch: int,
             pcfg_run=pcfg_step, moe_replica_ids=moe_replica_ids,
             moe_residency_ids=moe_residency_ids,
             moe_layer_diags=moe_layer_diags)
-        if S == 1:
-            logits = logits_head(h[:, -1], _vocab_w(params),
-                                 real_vocab=cfg.vocab_size,
-                                 softcap=cfg.final_logit_softcap)
-        else:
-            logits = logits_head(h.reshape(B * S, -1), _vocab_w(params),
-                                 real_vocab=cfg.vocab_size,
-                                 softcap=cfg.final_logit_softcap)
-            logits = logits.reshape(B, S, -1)
+        with jax.named_scope("lm_head"):
+            if S == 1:
+                logits = logits_head(h[:, -1], _vocab_w(params),
+                                     real_vocab=cfg.vocab_size,
+                                     softcap=cfg.final_logit_softcap)
+            else:
+                logits = logits_head(h.reshape(B * S, -1),
+                                     _vocab_w(params),
+                                     real_vocab=cfg.vocab_size,
+                                     softcap=cfg.final_logit_softcap)
+                logits = logits.reshape(B, S, -1)
         out = dict(caches)
         out["stack"] = new_stack
         return logits, out, new_pos, diags

@@ -124,8 +124,9 @@ def _apply_one_layer(x: jnp.ndarray, p: Dict[str, Any], kind: str,
                              replica_ids=moe_replica_ids,
                              residency_ids=moe_residency_ids)
         if "shared_mlp" in p:
-            y = y + mlp(h, p["shared_mlp"],
-                        "swiglu" if cfg.act == "swiglu" else cfg.act)
+            with jax.named_scope("shared_expert"):
+                y = y + mlp(h, p["shared_mlp"],
+                            "swiglu" if cfg.act == "swiglu" else cfg.act)
         # collapse the leading batch-shard dim only: scalar diags -> scalars,
         # vector diags (rank_load/expert_load) keep their trailing axis
         diag = {k: v.mean(axis=0) for k, v in mdiag.items()}

@@ -98,7 +98,6 @@ each combination's status).
 from __future__ import annotations
 
 import contextlib
-import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -109,7 +108,6 @@ import numpy as np
 
 from repro.configs.base import round_up
 from repro.core.prefetch import stage_expert_rows
-from repro.kernels.paged_attention.ops import largest_block_divisor
 from repro.models import attention as attention_dispatch
 from repro.serve.arrivals import WallClock
 from repro.serve.frontend import AdmissionFront
@@ -337,7 +335,7 @@ class ServeEngine:
             self._rebalancer = ExpertRebalancer(topo, ecfg.replica_slots)
             self._replica_ids = np.full(
                 (topo.num_ranks, ecfg.replica_slots), -1, np.int32)
-            self._swap_fn = jax.jit(_swap_replica_weights)
+            self._swap_fn = jax.jit(swap_replicas)
         # --- tiered expert residency (serve/residency.py) ---
         self._residency: Optional[ExpertResidencyManager] = None
         self._residency_ids: Optional[np.ndarray] = None
@@ -373,7 +371,7 @@ class ServeEngine:
             # one padded stage width => one jit entry across all swaps
             self._stage_width = max(
                 topo.num_ranks * self._residency.W, 1)
-            self._stage_fn = jax.jit(_stage_resident_weights)
+            self._stage_fn = jax.jit(stage_weights)
         self._proposer = (make_proposer(ecfg.speculative_policy)
                           if self._spec else None)
 
@@ -452,17 +450,6 @@ class ServeEngine:
         self.handoffs_imported = 0
         self.handoff_bytes_out = 0
         self.handoff_bytes_in = 0
-        # --- per-phase attention byte model (metrics.record_phase) ---
-        # bytes one KV token costs to read across the stack (K + V, every
-        # layer), and the slab block size the fused prefill path derives —
-        # must mirror attention_block's largest_block_divisor choice so the
-        # analytic bytes match what the kernel's causal pruning touches
-        kvb = {"float32": 4, "bfloat16": 2}.get(cfg.dtype, 4)
-        self._kv_token_bytes = (2 * cfg.num_layers
-                                * (cfg.num_kv_heads or cfg.num_heads)
-                                * cfg.resolved_head_dim * kvb)
-        self._scratch_len = self.kv.s_pad
-        self._slab_bs = largest_block_divisor(self._scratch_len)
         # attention dispatch-log snapshot taken right after warmup's traces;
         # when warmup() is skipped (tests drive run() directly) report()
         # falls back to the live log, which this reset scopes to the
@@ -810,34 +797,6 @@ class ServeEngine:
                     break
 
     # ------------------------------------------------------------------
-    def _attn_kv_bytes(self, span: int) -> int:
-        """Analytic attention-read bytes for one decode/verify step whose
-        deepest read per active row is ``pos + span``: the fused kernel
-        touches each row's live block-rounded chain; the reference gather
-        materializes every row's whole [L_max] logical view."""
-        bs = self.ecfg.kv_block_size
-        if self._paged:
-            if self.ecfg.fused_paged_attention:
-                lens = self.pos[self.active] + span
-                toks = int(np.sum(-(-lens // bs) * bs))
-            else:
-                toks = self.ecfg.max_slots * self.blocks_per_slot * bs
-        else:
-            toks = self.ecfg.max_slots * self.ecfg.max_seq_len
-        return toks * self._kv_token_bytes
-
-    def _prefill_kv_bytes(self, upto: int) -> int:
-        """Analytic attention-read bytes for one prefill chunk whose
-        deepest position is ``upto``: the q-tiled kernel's causal pruning
-        stops at the slab-block-rounded write frontier; the chunked
-        reference scans the whole scratch slab."""
-        if self._paged and self.ecfg.fused_paged_attention:
-            toks = -(-upto // self._slab_bs) * self._slab_bs
-        else:
-            toks = self._scratch_len
-        return toks * self._kv_token_bytes
-
-    # ------------------------------------------------------------------
     def _next_key(self, stream_key, idx: int):
         return self.core.next_key(stream_key, idx)
 
@@ -855,36 +814,40 @@ class ServeEngine:
                 # request must not inherit the previous one's fold
                 self.kv.begin_prefill()
             st = self._pf
-            t0 = time.perf_counter()
-            if self._sharing and st.prefill_pos > 0 and not st.prefix_loaded:
-                # mid-prompt restart off a cached prefix: the uncached
-                # tail's attention reads the prefix K/V from the scratch,
-                # so gather it out of the shared blocks first
-                with self._ctx():
-                    self._scratch = self._gather_fn(
-                        self.pool, self._scratch, self._bt_row(st),
-                        np.int32(st.prefill_pos))
-                st.prefix_loaded = True
+            if st.prefill_start_time is None:
+                # the step's clock reading: prefill runs first in a step
+                st.prefill_start_time = now
             seq = st.prefill_tokens
             start, L = st.prefill_pos, st.prefill_len
             n = min(C, L - start)
-            chunk = np.zeros((1, C), np.int32)
-            chunk[0, :n] = seq[start:start + n]
-            key = self._next_key(self._pf_key, self._chunk_idx)
-            self._chunk_idx += 1
-            with self._ctx():
-                logits, self._scratch, _, diags = self._prefill_fn(
-                    self.params, chunk, self._scratch, np.int32(start),
-                    np.int32(n - 1), key, self._replica_ids)
-                if self._paged:
-                    # finished chunk -> straight into the allocated blocks
-                    # (valid_to diverts ring-leaf pad writes to the null
-                    # block so they cannot clobber in-window ring slots)
-                    self.pool = self._write_fn(
-                        self.pool, self._scratch, self._bt_row(st),
-                        np.int32(start), np.int32(start + n))
-                jax.block_until_ready(logits)
-            dt = time.perf_counter() - t0
+            with self.metrics.span("serve.prefill", rid=st.req.rid,
+                                   tokens=n, start=start) as sp:
+                if self._sharing and start > 0 and not st.prefix_loaded:
+                    # mid-prompt restart off a cached prefix: the uncached
+                    # tail's attention reads the prefix K/V from the
+                    # scratch, so gather it out of the shared blocks first
+                    with self._ctx():
+                        self._scratch = self._gather_fn(
+                            self.pool, self._scratch, self._bt_row(st),
+                            np.int32(start))
+                    st.prefix_loaded = True
+                chunk = np.zeros((1, C), np.int32)
+                chunk[0, :n] = seq[start:start + n]
+                key = self._next_key(self._pf_key, self._chunk_idx)
+                self._chunk_idx += 1
+                with self._ctx():
+                    logits, self._scratch, _, diags = self._prefill_fn(
+                        self.params, chunk, self._scratch, np.int32(start),
+                        np.int32(n - 1), key, self._replica_ids)
+                    if self._paged:
+                        # finished chunk -> straight into the allocated
+                        # blocks (valid_to diverts ring-leaf pad writes to
+                        # the null block so they cannot clobber in-window
+                        # ring slots)
+                        self.pool = self._write_fn(
+                            self.pool, self._scratch, self._bt_row(st),
+                            np.int32(start), np.int32(start + n))
+                    jax.block_until_ready(logits)
             st.prefill_pos += n
             if self._sharing:
                 # every block fully covered by committed K/V joins the
@@ -900,7 +863,7 @@ class ServeEngine:
                 ("prefix_tail" if (self._sharing
                                    and (st.cached_prefix_tokens or 0) > 0)
                  else "prefill"),
-                n, dt, self._prefill_kv_bytes(start + n))
+                n, sp.seconds)
             did = True
             if st.prefill_done:
                 if st.resumed:
@@ -918,10 +881,11 @@ class ServeEngine:
                     self._activate(st, L, st.output[-1])
                     self._pf = None
                     continue
-                first = sample_np(np.asarray(logits)[0], self._samp_rng,
-                                  temperature=self.ecfg.temperature,
-                                  top_k=self.ecfg.top_k,
-                                  top_p=self.ecfg.top_p)
+                with self.metrics.span("serve.first_token", rid=st.req.rid):
+                    first = sample_np(np.asarray(logits)[0], self._samp_rng,
+                                      temperature=self.ecfg.temperature,
+                                      top_k=self.ecfg.top_k,
+                                      top_p=self.ecfg.top_p)
                 if not self._paged:
                     with self._ctx():
                         self.pool = self._write_fn(self.pool, self._scratch,
@@ -949,27 +913,35 @@ class ServeEngine:
         if self._spec:
             return self._speculative_decode_work(now)
         if self._paged and self.active.any():
-            self._ensure_decode_blocks()
+            with self.metrics.span("serve.ensure_blocks"):
+                self._ensure_decode_blocks()
         if not self.active.any():
             return False
         self._apply_pending_stage()
         key = self._next_key(self._dec_key, self._step_idx)
         bt_args = (self.block_table.copy(),) if self._paged else ()
-        t0 = time.perf_counter()
-        with self._ctx():
-            nxt, self.pool, diags = self._decode_fn(
-                self.params, self.tok[:, None], self.pool, self.pos,
-                *bt_args, key, self.active.copy(), self._replica_ids,
-                self._residency_ids)
-        nxt = np.asarray(nxt)
-        dt = time.perf_counter() - t0
+        rows = int(self.active.sum())
+        with self.metrics.span("serve.decode", rows=rows) as sp:
+            with self._ctx():
+                nxt, self.pool, diags = self._decode_fn(
+                    self.params, self.tok[:, None], self.pool, self.pos,
+                    *bt_args, key, self.active.copy(), self._replica_ids,
+                    self._residency_ids)
+            nxt = np.asarray(nxt)
+        self.metrics.record_phase("decode", rows, sp.seconds)
+        with self.metrics.span("serve.commit"):
+            self._commit_decode(nxt, diags)
+        return True
+
+    def _commit_decode(self, nxt: np.ndarray, diags) -> None:
+        """Everything after a decode step's tokens are back on the host:
+        diagnostics, load and residency observation, KV occupancy, and
+        each slot's new token (finishing the requests that are done)."""
         now = self.clock.now()       # post-sync: token times include compute
         diags = dict(diags)
         layer_loads = diags.pop("expert_load_layers", None)
         self.metrics.record_step(diags if self.cfg.is_moe else {},
                                  int(self.active.sum()), phase="decode")
-        self.metrics.record_phase("decode", int(self.active.sum()), dt,
-                                  self._attn_kv_bytes(1))
         self._observe_load(diags)
         self._observe_residency(layer_loads)
         if self._paged:
@@ -992,7 +964,6 @@ class ServeEngine:
                 self._finish(st, now)
             else:
                 self.tok[s] = t
-        return True
 
     def _speculative_decode_work(self, now: float) -> bool:
         """One speculative decode step: draft up to k tokens per active
@@ -1007,12 +978,12 @@ class ServeEngine:
         sharing, preemption-by-recompute, and the prefix index all stay
         token-exact."""
         if self.active.any():
-            self._ensure_decode_blocks()
+            with self.metrics.span("serve.ensure_blocks"):
+                self._ensure_decode_blocks()
         if not self.active.any():
             return False
         self._apply_pending_stage()
         B, k = self.ecfg.max_slots, self.ecfg.speculative_k
-        bs = self.ecfg.kv_block_size
         toks = np.zeros((B, k + 1), np.int32)
         draft_len = np.zeros((B,), np.int32)
         for s in np.nonzero(self.active)[0]:
@@ -1028,22 +999,31 @@ class ServeEngine:
                 toks[s, 1:1 + len(d)] = d
                 draft_len[s] = len(d)
         key = self._next_key(self._dec_key, self._step_idx)
-        t0 = time.perf_counter()
-        with self._ctx():
-            logits, self.pool, diags = self._decode_fn(
-                self.params, toks, self.pool, self.pos,
-                self.block_table.copy(), key, self.active.copy(),
-                self._replica_ids, self._residency_ids)
-        logits = np.asarray(logits)          # [B, k+1, V]
-        dt = time.perf_counter() - t0
+        with self.metrics.span("serve.verify",
+                               rows=int(self.active.sum())) as sp:
+            with self._ctx():
+                logits, self.pool, diags = self._decode_fn(
+                    self.params, toks, self.pool, self.pos,
+                    self.block_table.copy(), key, self.active.copy(),
+                    self._replica_ids, self._residency_ids)
+            logits = np.asarray(logits)          # [B, k+1, V]
+        with self.metrics.span("serve.commit"):
+            total_commit = self._commit_verify(toks, draft_len, logits,
+                                               diags)
+        self.metrics.record_phase("verify", total_commit, sp.seconds)
+        return True
+
+    def _commit_verify(self, toks: np.ndarray, draft_len: np.ndarray,
+                       logits: np.ndarray, diags) -> int:
+        """Everything after a verify step's logits are back on the host:
+        diagnostics, acceptance of each slot's drafts and the committed
+        tokens; returns the number of tokens committed."""
+        bs = self.ecfg.kv_block_size
         now = self.clock.now()   # post-sync: token times include compute
         diags = dict(diags)
         layer_loads = diags.pop("expert_load_layers", None)
         self.metrics.record_step(diags if self.cfg.is_moe else {},
                                  int(self.active.sum()), phase="decode")
-        # bytes computed against pre-commit positions: the verify window
-        # reads each active row's chain up to pos + k + 1
-        verify_bytes = self._attn_kv_bytes(k + 1)
         self._observe_load(diags)
         self._observe_residency(layer_loads)
         self.metrics.record_kv(self._alloc.blocks_in_use,
@@ -1087,8 +1067,7 @@ class ServeEngine:
                 self._finish(st, now)
             else:
                 self.tok[s] = st.output[-1]
-        self.metrics.record_phase("verify", total_commit, dt, verify_bytes)
-        return True
+        return total_commit
 
     # ------------------------------------------------------------------
     # prefill→decode handoff (split engine roles; serve/kvstore.py)
@@ -1110,7 +1089,8 @@ class ServeEngine:
             cached_prefix_tokens=int(st.cached_prefix_tokens or 0),
             arrival_time=st.req.arrival_time,
             admitted_time=st.admitted_time,
-            first_token_time=st.first_token_time)
+            first_token_time=st.first_token_time,
+            prefill_start_time=st.prefill_start_time)
         self._handoffs_out.append(rec)
         self.handoffs_exported += 1
         self.handoff_bytes_out += rec.nbytes
@@ -1163,6 +1143,7 @@ class ServeEngine:
                       arrival_time=rec.arrival_time, eos_id=rec.eos_id)
         st = RequestState(req=req, slot=-1,
                           admitted_time=rec.admitted_time,
+                          prefill_start_time=rec.prefill_start_time,
                           first_token_time=rec.first_token_time,
                           output=list(rec.output), prefill_pos=rec.pos,
                           cached_prefix_tokens=rec.cached_prefix_tokens)
@@ -1407,23 +1388,49 @@ class ServeEngine:
         advances a VirtualClock, so per-replica reads would skew time);
         ``wait_when_idle=False`` defers the idle wait to the router, which
         knows every replica's next arrival.  Returns whether any prefill
-        or decode work ran."""
+        or decode work ran.
+
+        A step with work runs inside the ``serve.step`` span (its children
+        name each part, module docstring of metrics.py); a tick with
+        nothing to do waits inside ``serve.idle`` instead, so
+        ``serve.step``'s count is the number of working steps."""
         if now is None:
             now = self.clock.now()
-        self._admit(now)
-        did = self._prefill_work(now)
-        did = self._decode_work(now) or did
+        did = False
+        if self._work_ready(now):
+            with self.metrics.span("serve.step", step_num=self._step_idx):
+                with self.metrics.span("serve.admit"):
+                    self._admit(now)
+                did = self._prefill_work(now)
+                did = self._decode_work(now) or did
+                self._end_step()
+        else:
+            self._end_step()
+        if not did and wait_when_idle:
+            nxt = self.queue.next_arrival()
+            if nxt is not None:
+                with self.metrics.span("serve.idle"):
+                    self.clock.wait(min(max(nxt - now, 0.0), 0.01))
+        return did
+
+    def _work_ready(self, now: float) -> bool:
+        """Whether this tick has prefill or decode work, or a request
+        admission would place (a preempted one, or an arrival due by
+        ``now``)."""
+        return bool(self.active.any() or self._pf is not None
+                    or self._pf_queue
+                    or (self.free_slots
+                        and (self._resume
+                             or self.queue.peek_ready(now) is not None)))
+
+    def _end_step(self) -> None:
         self._step_idx += 1
         if self._rebalancer is not None \
                 and self.ecfg.rebalance_interval > 0 \
                 and self._step_idx % self.ecfg.rebalance_interval == 0 \
                 and self._rebalancer.steps_observed > 0:
-            self._rebalance_now()
-        if not did and wait_when_idle:
-            nxt = self.queue.next_arrival()
-            if nxt is not None:
-                self.clock.wait(min(max(nxt - now, 0.0), 0.01))
-        return did
+            with self.metrics.span("serve.rebalance"):
+                self._rebalance_now()
 
     def run(self, requests: Sequence[Request] = (), *,
             max_steps: int = 1_000_000) -> Dict[str, Any]:
@@ -1567,7 +1574,7 @@ _EXPERT_LEAF_NAMES = ("w_in", "w_out", "w_gate")
 
 def _collect_expert_leaves(params) -> List:
     """Every MoE expert weight leaf, in the deterministic order
-    ``_stage_resident_weights`` visits them.  MoE parameter dicts are
+    ``stage_weights`` visits them.  MoE parameter dicts are
     the ones carrying a ``router`` — dense MLP blocks reuse the
     ``w_in``/``w_out`` names but have no router."""
     out: List = []
@@ -1588,11 +1595,11 @@ def _collect_expert_leaves(params) -> List:
     return out
 
 
-def _stage_resident_weights(params, rows, vals):
+def stage_weights(params, rows, vals):
     """Scatter staged expert rows into every expert weight leaf.
 
     ``rows`` [n] indexes the rank-major stacked expert-row axis (the
-    same layout ``_swap_replica_weights`` gathers from); ``vals`` is the
+    same layout ``swap_replicas`` gathers from); ``vals`` is the
     flat list of gathered host-tier slices in ``_collect_expert_leaves``
     order.  The writes are value-identity (the emulated host tier is a
     bit-exact copy of the device rows) — what's real is the dispatched
@@ -1616,7 +1623,7 @@ def _stage_resident_weights(params, rows, vals):
     return walk(params)
 
 
-def _swap_replica_weights(params, rows):
+def swap_replicas(params, rows):
     """Gather expert weight rows into every replica leaf of the parameter
     tree.  ``rows`` [G*R] indexes the rank-major stacked expert-row axis
     (``row = host_rank * experts_per_rank + local_slot``, the layout

@@ -67,6 +67,7 @@ class HandoffRecord:
     arrival_time: float = 0.0
     admitted_time: float = 0.0
     first_token_time: float = 0.0
+    prefill_start_time: Optional[float] = None
 
     @property
     def nbytes(self) -> int:
@@ -84,7 +85,9 @@ class HandoffRecord:
                              -1 if self.eos_id is None else self.eos_id,
                              self.cached_prefix_tokens], np.int64)
         times = np.asarray([self.arrival_time, self.admitted_time,
-                            self.first_token_time], np.float64)
+                            self.first_token_time,
+                            np.nan if self.prefill_start_time is None
+                            else self.prefill_start_time], np.float64)
         payload = {f"kv_{i}": np.frombuffer(a.tobytes(), np.uint8)
                    for i, a in enumerate(self.kv)}
         np.savez(buf, header=header, times=times,
@@ -113,7 +116,9 @@ class HandoffRecord:
                    kv=kv, cached_prefix_tokens=int(h[6]),
                    arrival_time=float(z["times"][0]),
                    admitted_time=float(z["times"][1]),
-                   first_token_time=float(z["times"][2]))
+                   first_token_time=float(z["times"][2]),
+                   prefill_start_time=(None if np.isnan(z["times"][3])
+                                       else float(z["times"][3])))
 
 
 class KVOwner:
@@ -205,21 +210,25 @@ class KVOwner:
             self.scratch = place_cache(
                 lambda: model.init_cache(1, s_pad, False), mesh)
             ring_mods = self.ring_mods if self.ring else None
-            self.write_fn = jax.jit(
-                lambda pool, scratch, bt_row, start, valid_to:
-                write_chunk_blocks(
+
+            # named entries: a trace's modules are jit_<name>
+            def pool_write(pool, scratch, bt_row, start, valid_to):
+                return write_chunk_blocks(
                     pool, scratch, bt_row, start, chunk=C, block_size=bs,
                     seq_axes=self.seq_axes, ring_mods=ring_mods,
-                    valid_to=valid_to))
+                    valid_to=valid_to)
+            self.write_fn = jax.jit(pool_write)
             if self.sharing:
-                self.gather_fn = jax.jit(
-                    lambda pool, scratch, bt_row, n: gather_prefix_blocks(
+                def prefix_gather(pool, scratch, bt_row, n):
+                    return gather_prefix_blocks(
                         pool, scratch, bt_row, n, s_pad=s_pad,
-                        block_size=bs, seq_axes=self.seq_axes))
-                self.copy_fn = jax.jit(
-                    lambda pool, src, dst: copy_block(
-                        pool, src, dst, block_size=bs,
-                        seq_axes=self.seq_axes))
+                        block_size=bs, seq_axes=self.seq_axes)
+
+                def block_copy(pool, src, dst):
+                    return copy_block(pool, src, dst, block_size=bs,
+                                      seq_axes=self.seq_axes)
+                self.gather_fn = jax.jit(prefix_gather)
+                self.copy_fn = jax.jit(block_copy)
         else:
             self.s_pad = ecfg.max_seq_len
             self.blocks_per_slot = 0
@@ -231,9 +240,10 @@ class KVOwner:
                 lambda: model.init_cache(B, ecfg.max_seq_len), mesh)
             self.scratch = place_cache(
                 lambda: model.init_cache(1, ecfg.max_seq_len), mesh)
-            self.write_fn = jax.jit(
-                lambda pool, scratch, slot: write_slot(pool, scratch, slot,
-                                                       self.batch_axes))
+
+            def pool_write(pool, scratch, slot):
+                return write_slot(pool, scratch, slot, self.batch_axes)
+            self.write_fn = jax.jit(pool_write)
 
     # ------------------------------------------------------------------
     # SequenceStateStore protocol (serve/statestore.py)

@@ -1,8 +1,11 @@
-"""Serving metrics: per-request latency accounting plus per-step MoE
-schedule diagnostics.
+"""Serving metrics: per-request latency accounting, per-step MoE
+schedule diagnostics, and the engine's spans.
 
 Per request (the paper's §5 serving metrics):
-  * TTFT — first_token_time - arrival_time (queueing + prefill)
+  * TTFT — first_token_time - arrival_time, split three ways:
+    ``queue_delay`` (arrival to admission), ``prefill_wait`` (admission
+    to the start of the request's first prefill chunk) and the prefill
+    itself (first chunk to first token)
   * TPOT — mean inter-token time over the decode phase
   * e2e  — finish_time - arrival_time
 
@@ -16,23 +19,36 @@ The paged engine additionally records per-step KV-block occupancy
 ``kv_utilization`` / ``preemptions``.  Speculative decoding records
 drafted/accepted/committed token counts per verify step, reported as a
 ``speculative`` sub-dict (acceptance_rate, tokens per slot-step, steps
-per committed token).  ``record_phase`` accumulates a per-phase kernel
-breakdown (prefill / prefix_tail / decode / verify tokens-per-second and
-analytic attention KV bytes-touched), reported as ``phases``.
-With tiered expert residency the engine attaches a ``residency``
-sub-dict (hit_rate, stall_units, swaps, prefetches, bytes_staged) from
-the residency manager's window counters.  ``report()`` is JSON-safe on
-an empty measurement window: percentile reductions over zero requests
-come back as ``None``, never NaN.
+per committed token).  ``record_phase`` accumulates per-phase tokens and
+seconds (prefill / prefix_tail / decode / verify; the seconds are those
+of the engine span around the jitted call and its sync), reported as
+``phases``.  With tiered expert residency the engine attaches a
+``residency`` sub-dict (hit_rate, stall_units, swaps, prefetches,
+bytes_staged) from the residency manager's window counters.
+``report()`` is JSON-safe on an empty measurement window: percentile
+reductions over zero requests come back as ``None``, never NaN.
+
+**Spans.**  ``ServeMetrics.span(name, **args)`` is the engine's one
+timing mechanism: a context manager that opens a
+``jax.profiler.TraceAnnotation`` (so the span lands in a profiler trace
+on the device ops' clock, with ``args`` as its arguments; a
+``StepTraceAnnotation`` when ``step_num`` is among them) and adds the
+span's count, seconds and self seconds (seconds minus those of the spans
+opened inside it) to window totals.  It is always on: with the profiler
+off a span costs one ``perf_counter`` pair, a dict update and an
+inactive trace annotation.  The totals are the ``spans`` section,
+``{name: {"count", "seconds", "self_seconds"}}``, reset with the
+metrics object.  The engine's span names start ``serve.`` (listed in
+serve/README.md, "Report schema").
 
 **Section convention.**  Every optional subsystem block in a report —
-``speculative``, ``phases``, ``load_balance``, ``residency`` here;
-``state_pool`` from the engine; ``fleet`` from the router — attaches
-through one mechanism instead of ad-hoc conditional appends: a *section
-function* returns the section dict, or a falsy value to omit the section
-this window.  ``ServeMetrics.register_section(name, fn)`` registers one
-on a metrics object (the built-ins register themselves the same way at
-construction, so subsystem sections and core sections are
+``speculative``, ``phases``, ``load_balance``, ``residency``, ``spans``
+here; ``state_pool`` from the engine; ``fleet`` from the router —
+attaches through one mechanism instead of ad-hoc conditional appends: a
+*section function* returns the section dict, or a falsy value to omit
+the section this window.  ``ServeMetrics.register_section(name, fn)``
+registers one on a metrics object (the built-ins register themselves the
+same way at construction, so subsystem sections and core sections are
 indistinguishable in ``report()``); the module-level ``section(rep,
 name, fn)`` applies the identical rule to dicts assembled outside a
 ``ServeMetrics`` (the engine's and the fleet router's reports).  The
@@ -40,9 +56,11 @@ full schema is documented in serve/README.md ("Report schema").
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+import time
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.serve.request import RequestState
@@ -81,6 +99,43 @@ def _json_safe(x):
     return x
 
 
+class Span:
+    """One open span of ``ServeMetrics.span``; ``seconds`` holds its
+    duration once it has closed."""
+    __slots__ = ("name", "seconds", "_inner", "_t0", "_annotation",
+                 "_owner")
+
+    def __init__(self, owner: "ServeMetrics", name: str, annotation):
+        self.name = name
+        self.seconds = 0.0
+        self._inner = 0.0               # seconds of the spans inside it
+        self._t0 = 0.0
+        self._annotation = annotation
+        self._owner = owner
+
+    def __enter__(self) -> "Span":
+        self._annotation.__enter__()
+        self._owner._open.append(self)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        open_ = self._owner._open
+        open_.pop()
+        if open_:
+            open_[-1]._inner += dt
+        self.seconds = dt
+        tot = self._owner.span_totals.get(self.name)
+        if tot is None:
+            tot = self._owner.span_totals[self.name] = [0, 0.0, 0.0]
+        tot[0] += 1
+        tot[1] += dt
+        tot[2] += dt - self._inner
+        return False
+
+
 @dataclass
 class RequestRecord:
     """Immutable latency record for one finished request."""
@@ -93,6 +148,7 @@ class RequestRecord:
     finish_time: float
     cached_prefix_tokens: int = 0   # prompt tokens served from the prefix
     #                                 cache at first admission
+    prefill_start_time: Optional[float] = None   # first prefill chunk
 
     @property
     def ttft(self) -> float:
@@ -115,6 +171,11 @@ class RequestRecord:
             "n_generated": self.n_generated,
             "arrival_time": self.arrival_time,
             "queue_delay": self.admitted_time - self.arrival_time,
+            # admission to the first prefill chunk: the wait in the
+            # prefill line (None for a request that never prefilled here)
+            "prefill_wait": (None if self.prefill_start_time is None
+                             else self.prefill_start_time
+                             - self.admitted_time),
             "ttft": self.ttft, "tpot": self.tpot, "e2e": self.e2e,
             "cached_prefix_tokens": self.cached_prefix_tokens,
         }
@@ -145,16 +206,16 @@ class ServeMetrics:
         self.spec_drafted: int = 0              # draft tokens proposed
         self.spec_accepted: int = 0             # draft tokens accepted
         self.spec_committed: int = 0            # tokens committed by verify
-        # --- per-phase kernel accounting (prefill / prefix_tail / decode /
-        # verify): committed-or-consumed tokens, wall seconds around the
-        # jitted call, and the analytic KV bytes the attention path read
-        # (gather: the whole [B, L_max] logical view; fused: the live
-        # block-rounded chains) — the measured artifact behind the "no hot
-        # phase dispatches the logical gather" claim
+        # --- per-phase accounting (prefill / prefix_tail / decode /
+        # verify): committed-or-consumed tokens and the seconds of the
+        # engine span from the jitted call's dispatch to its synced result
         self.phase_tokens: Dict[str, int] = {}
         self.phase_seconds: Dict[str, float] = {}
-        self.phase_kv_bytes: Dict[str, int] = {}
         self.phase_steps: Dict[str, int] = {}
+        # --- spans (module docstring "Spans"): name -> [count, seconds,
+        # self seconds]; _open is the stack of spans now open
+        self.span_totals: Dict[str, List[float]] = {}
+        self._open: List[Span] = []
         # --- tiered expert residency (serve/residency.py) ---
         # window counter dict (hits, misses, lookups, swaps, prefetches,
         # stall_units, bytes_staged, hit_rate) set by the engine's
@@ -173,12 +234,22 @@ class ServeMetrics:
                               lambda: self.residency
                               and dict(self.residency))
         self.register_section("load_balance", self._load_balance)
+        self.register_section("spans", self._spans_section)
 
     def register_section(self, name: str, fn: SectionFn) -> None:
         """Register a report section provider (last registration per name
         wins).  ``fn()`` runs at ``report()`` time; a falsy return omits
         the section for this window."""
         self._sections[name] = fn
+
+    def span(self, name: str, **args) -> Span:
+        """A context manager timing one span (module docstring "Spans"):
+        its ``seconds`` are set when it closes.  ``args`` become the
+        trace annotation's arguments; with ``step_num`` among them the
+        annotation is a ``StepTraceAnnotation``."""
+        ann = (jax.profiler.StepTraceAnnotation if "step_num" in args
+               else jax.profiler.TraceAnnotation)(name, **args)
+        return Span(self, name, ann)
 
     @property
     def empty(self) -> bool:
@@ -202,17 +273,14 @@ class ServeMetrics:
                 self.moe_diags.setdefault(f"{phase}/{k}", []).append(
                     float(arr))
 
-    def record_phase(self, phase: str, tokens: int, seconds: float,
-                     kv_bytes: int) -> None:
+    def record_phase(self, phase: str, tokens: int, seconds: float) -> None:
         """One engine step's contribution to a phase (prefill /
-        prefix_tail / decode / verify): tokens processed, wall seconds
-        around the synced jitted call, analytic attention KV bytes."""
+        prefix_tail / decode / verify): tokens processed and the seconds
+        of the span around the synced jitted call."""
         self.phase_tokens[phase] = self.phase_tokens.get(phase, 0) \
             + int(tokens)
         self.phase_seconds[phase] = self.phase_seconds.get(phase, 0.0) \
             + float(seconds)
-        self.phase_kv_bytes[phase] = self.phase_kv_bytes.get(phase, 0) \
-            + int(kv_bytes)
         self.phase_steps[phase] = self.phase_steps.get(phase, 0) + 1
 
     def record_kv(self, blocks_in_use: int, blocks_total: int) -> None:
@@ -228,7 +296,8 @@ class ServeMetrics:
             admitted_time=st.admitted_time,
             first_token_time=st.first_token_time,
             finish_time=st.finish_time,
-            cached_prefix_tokens=st.cached_prefix_tokens or 0)
+            cached_prefix_tokens=st.cached_prefix_tokens or 0,
+            prefill_start_time=st.prefill_start_time)
         self.requests.append(rec)
         if self._t_first_arrival is None \
                 or rec.arrival_time < self._t_first_arrival:
@@ -323,14 +392,13 @@ class ServeMetrics:
                         / self.phase_seconds[ph]
                         if self.phase_seconds.get(ph, 0.0) > 0
                         else None),
-                    "kv_bytes_touched": self.phase_kv_bytes.get(ph, 0),
-                    "kv_bytes_per_token": (
-                        self.phase_kv_bytes.get(ph, 0)
-                        / self.phase_tokens[ph]
-                        if self.phase_tokens.get(ph, 0) else None),
                 }
                 for ph in sorted(self.phase_steps)
         }
+
+    def _spans_section(self) -> Dict[str, Dict[str, float]]:
+        return {name: {"count": int(c), "seconds": sec, "self_seconds": own}
+                for name, (c, sec, own) in sorted(self.span_totals.items())}
 
     def _load_balance(self) -> Dict[str, Any]:
         """Paper §5 load metrics per phase, from the per-step vector

@@ -72,7 +72,8 @@ class RequestState:
     #                                             admission via a cache hit
     prefix_loaded: bool = False          # cached prefix gathered to scratch
     # --- timestamps on the engine clock ---
-    admitted_time: float = 0.0           # slot reserved / prefill started
+    admitted_time: float = 0.0           # slot reserved
+    prefill_start_time: Optional[float] = None  # first prefill chunk began
     first_token_time: float = 0.0        # last prefill chunk done (TTFT point)
     finish_time: float = 0.0
 

@@ -191,9 +191,10 @@ class SlotStateStore:
         # immutable, so holding the initial scratch pytree (never fed back
         # through any jitted update) is a zero-copy template
         self._scratch0 = self.scratch
-        self.write_fn = jax.jit(
-            lambda pool, scratch, slot: write_slot(pool, scratch, slot,
-                                                   self.batch_axes))
+
+        def pool_write(pool, scratch, slot):
+            return write_slot(pool, scratch, slot, self.batch_axes)
+        self.write_fn = jax.jit(pool_write)
         self.scratch_resets = 0
 
     # ------------------------------------------------------------------

@@ -40,22 +40,26 @@ class StepCore:
         self.samp_rng = (np.random.default_rng(ecfg.skew_seed + 101)
                          if self.sample else None)
 
+        # every jitted entry is a named function, so a trace's modules
+        # read jit_decode_step, jit_verify_step and jit_prefill_chunk
         if ecfg.paged:
             if self.spec:
                 # speculative verify IS the decode step: one [B, k+1]
                 # multi-token forward returning logits at every window
                 # position; acceptance/sampling run host-side
-                self.decode_fn = jax.jit(
-                    lambda p, t, c, pos, bt, k, a, rep, res:
-                        self._verify_core(p, t, c, pos, k, a, bt, rep, res))
+                def verify_step(p, t, c, pos, bt, k, a, rep, res):
+                    return self._verify_core(p, t, c, pos, k, a, bt, rep,
+                                             res)
+                self.decode_fn = jax.jit(verify_step)
             else:
-                self.decode_fn = jax.jit(
-                    lambda p, t, c, pos, bt, k, a, rep, res:
-                        self._decode_core(p, t, c, pos, k, a, bt, rep, res))
+                def decode_step(p, t, c, pos, bt, k, a, rep, res):
+                    return self._decode_core(p, t, c, pos, k, a, bt, rep,
+                                             res)
+                self.decode_fn = jax.jit(decode_step)
         else:
-            self.decode_fn = jax.jit(
-                lambda p, t, c, pos, k, a, rep, res: self._decode_core(
-                    p, t, c, pos, k, a, None, rep, res))
+            def decode_step(p, t, c, pos, k, a, rep, res):
+                return self._decode_core(p, t, c, pos, k, a, None, rep, res)
+            self.decode_fn = jax.jit(decode_step)
         # replica ids ride along as a trailing traced arg so between-window
         # weight swaps never re-trace (None = no replica slots: an empty
         # pytree, same trace either way).  With fused_paged_attention the
@@ -67,10 +71,12 @@ class StepCore:
         # tokens through the grouped-GEMM kernel.
         pf_fused_attn = True if ecfg.fused_paged_attention else None
         pf_fused_moe = True if ecfg.fused_moe_gmm else None
-        self.prefill_fn = jax.jit(
-            lambda p, t, c, pos, last, key, rep: model.prefill_chunk(
+
+        def prefill_chunk(p, t, c, pos, last, key, rep):
+            return model.prefill_chunk(
                 p, t, c, pos, last, key, moe_replica_ids=rep,
-                fused_attention=pf_fused_attn, fused_moe=pf_fused_moe))
+                fused_attention=pf_fused_attn, fused_moe=pf_fused_moe)
+        self.prefill_fn = jax.jit(prefill_chunk)
 
     # ------------------------------------------------------------------
     def next_key(self, stream_key, idx: int):
@@ -100,9 +106,11 @@ class StepCore:
             moe_policy=self.moe_policy, moe_replica_ids=rep,
             moe_residency_ids=res,
             moe_layer_diags=self.layer_diags, **kw)
-        nxt = sample_tokens(logits, samp_key,
-                            temperature=self.ecfg.temperature,
-                            top_k=self.ecfg.top_k, top_p=self.ecfg.top_p)
+        with jax.named_scope("sample"):
+            nxt = sample_tokens(logits, samp_key,
+                                temperature=self.ecfg.temperature,
+                                top_k=self.ecfg.top_k,
+                                top_p=self.ecfg.top_p)
         return nxt, pool, diags
 
     def _verify_core(self, params, toks, pool, pos, key, active, bt, rep,

@@ -426,3 +426,62 @@ def test_report_on_empty_window_is_json_safe():
     rep = eng.run([])                       # draining nothing also reports
     json.dumps(rep, allow_nan=False)
     assert rep["mean_occupancy"] == 0.0 and rep["max_occupancy"] == 0
+
+
+def test_engine_spans_count_steps_and_phases():
+    """The engine's spans: ``serve.step`` counts the working steps only
+    (idle ticks wait in ``serve.idle``), the prefill and decode spans
+    count the chunks and decode steps, the phase seconds are exactly the
+    spans' seconds, and each request's queue wait plus prefill wait stays
+    within its time to first token."""
+    import json
+
+    L = 10
+    model, params = _model(TINY, 2, 16)
+    eng = _engine(TINY, model, params, slots=2, prompt_len=L, max_new=4,
+                  chunk=4, paged=True, kv_block_size=4)
+    reqs = poisson_requests(5, rate=0.05, vocab_size=TINY.vocab_size,
+                            prompt_len=L, max_new_tokens=4, seed=3)
+    reqs[1].arrival_time = reqs[0].arrival_time   # two due at once
+    for r in reqs:
+        eng.submit(r)
+    working = idle = 0
+    while eng.has_work():
+        if eng.step():
+            working += 1
+        else:
+            idle += 1
+    rep = eng.report()
+    json.dumps(rep, allow_nan=False)
+    spans = rep["spans"]
+    assert idle > 0 and working > 0
+    assert set(spans) == {"serve.step", "serve.admit", "serve.prefill",
+                          "serve.first_token", "serve.ensure_blocks",
+                          "serve.decode", "serve.commit", "serve.idle"}
+    assert spans["serve.step"]["count"] == working
+    assert spans["serve.admit"]["count"] == working
+    assert spans["serve.idle"]["count"] == idle
+    assert spans["serve.prefill"]["count"] == rep["prefill_chunks"]
+    assert spans["serve.decode"]["count"] == rep["decode_steps"]
+    assert spans["serve.commit"]["count"] == rep["decode_steps"]
+    assert spans["serve.first_token"]["count"] == len(reqs)
+    ph = eng.metrics.phase_seconds
+    assert ph["prefill"] == pytest.approx(spans["serve.prefill"]["seconds"],
+                                          rel=1e-12)
+    assert ph["decode"] == pytest.approx(spans["serve.decode"]["seconds"],
+                                         rel=1e-12)
+    # a step's own time excludes its children's
+    step = spans["serve.step"]
+    inner = sum(spans[n]["seconds"] for n in
+                ("serve.admit", "serve.prefill", "serve.first_token",
+                 "serve.ensure_blocks", "serve.decode", "serve.commit"))
+    assert step["self_seconds"] == pytest.approx(step["seconds"] - inner,
+                                                 abs=1e-9)
+    assert step["self_seconds"] >= 0
+    for r in rep["requests"]:
+        assert r["prefill_wait"] is not None and r["prefill_wait"] >= 0
+        assert r["queue_delay"] + r["prefill_wait"] <= r["ttft"]
+    # a request admitted while another prefills waits in the prefill line
+    assert any(r["prefill_wait"] > 0 for r in rep["requests"])
+    eng.reset_metrics()
+    assert "spans" not in eng.report()

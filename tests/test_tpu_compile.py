@@ -101,3 +101,25 @@ def test_moe_gmm_compiles(one_chip, groups):
         _spec((groups, F, D), BF16, one_chip),
         _spec((groups,), jnp.int32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["paged_attention", "moe_gmm"])
+def test_kernels_carry_their_names(one_chip, kernel):
+    """Each Pallas call names its kernel, so a chip trace's custom call
+    reads ``paged_attention`` / ``moe_gmm`` instead of ``_kernel``."""
+    if kernel == "paged_attention":
+        fn = jax.jit(lambda q, k, v, bt, cl: paged_attention(
+            q, k, v, bt, cl, block_size=16, interpret=False))
+        args = _paged_args(one_chip, batch=8, q_len=1, hkv=H, hd=HD,
+                           block_size=16, n_blocks=48, pool_blocks=8 * 48 + 1)
+    else:
+        fn = jax.jit(lambda x, wi, wg, wo, sizes: grouped_ffn(
+            x, wi, wo, sizes, w_gate=wg, act="silu", use_pallas=True,
+            interpret=False))
+        args = (_spec((15 * 128, D), BF16, one_chip),
+                _spec((15, D, F), BF16, one_chip),
+                _spec((15, D, F), BF16, one_chip),
+                _spec((15, F, D), BF16, one_chip),
+                _spec((15,), jnp.int32, one_chip))
+    text = fn.lower(*args).as_text()
+    assert f'kernel_name = "{kernel}"' in text
