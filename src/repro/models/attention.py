@@ -60,11 +60,14 @@ def fallback_counts(log: Optional[list] = None) -> Dict[str, int]:
 
 
 def _record_dispatch(branch: str, *, fused: bool, requested: bool,
-                     strict: bool = False, reason: str = "") -> None:
+                     strict: bool = False, reason: str = "",
+                     **kernel) -> None:
+    """``kernel`` names the fused kernel a branch took and its static
+    tiling (e.g. ``kernel="paged_decode", pages_per_step=8``)."""
     if len(_dispatch_log) < _DISPATCH_LOG_CAP:
         _dispatch_log.append({"branch": branch, "fused": bool(fused),
                               "requested": bool(requested),
-                              "reason": reason})
+                              "reason": reason, **kernel})
     if requested and not fused and strict:
         raise FusedPathUnavailable(
             f"attention_block: use_pallas was explicitly required but the "
@@ -485,8 +488,12 @@ def attention_block(x: jnp.ndarray, p: Dict[str, jnp.ndarray],
                 q, k_cache, v_cache, block_table, cl, window=window,
                 block_size=block_size, softcap=softcap)
         elif use_pallas:
-            from repro.kernels.paged_attention.ops import paged_attention
-            _record_dispatch(branch, fused=True, requested=True)
+            from repro.kernels.paged_attention.ops import (decode_plan,
+                                                           paged_attention)
+            plan = (decode_plan(k_cache, block_size=block_size,
+                                n_blocks=block_table.shape[1], mesh=mesh)
+                    if S == 1 else {})
+            _record_dispatch(branch, fused=True, requested=True, **plan)
             out = paged_attention(q, k_cache, v_cache, block_table, cl,
                                   block_size=block_size, softcap=softcap,
                                   interpret=interpret, mesh=mesh)

@@ -1546,10 +1546,7 @@ class ServeEngine:
             branches: Dict[str, Dict[str, Any]] = {}
             for d in snap:
                 branches[d["branch"]] = {
-                    "fused": d["fused"],
-                    "requested": d["requested"],
-                    "reason": d.get("reason", ""),
-                }
+                    k: v for k, v in d.items() if k != "branch"}
             rep["attention_dispatch"] = branches
             rep["attention_fallbacks"] = \
                 attention_dispatch.fallback_counts(snap)

@@ -1,5 +1,6 @@
-"""Fused paged-attention decode kernel: interpret-mode parity vs the
-gather reference, jit stability, and end-to-end serve-stream identity."""
+"""Fused paged-attention kernels (decode and q-tiled): interpret-mode
+parity vs the gather reference, jit stability, and end-to-end
+serve-stream identity."""
 import functools
 
 import jax
@@ -8,7 +9,8 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ModelConfig, ParallelConfig
-from repro.kernels.paged_attention.ops import paged_attention
+from repro.kernels.paged_attention.ops import decode_plan, paged_attention
+from repro.kernels.paged_attention.paged_attention import paged_decode_kernel
 from repro.kernels.paged_attention.ref import paged_attention_ref
 from repro.models.attention import paged_decode_attention
 from repro.models.model import build_model
@@ -77,6 +79,54 @@ def test_paged_attention_parity(dtype, rep, softcap):
                                atol=_tol(dtype), rtol=_tol(dtype))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("rep", [1, 4])
+@pytest.mark.parametrize("softcap", [0.0, 30.0])
+@pytest.mark.parametrize("pages", [1, 3, 4])
+def test_paged_decode_kernel_parity(pages, softcap, rep, dtype):
+    """The decode kernel at P pages a grid step vs both references over a
+    7-page table (ragged last group at P = 3 and 4): an inactive row
+    (length 1, all-null table), lengths ending mid-group, exactly at a
+    group boundary and two groups deep, and full tables."""
+    bs, n_logical, Hkv, hd = 4, 7, 2, 16
+    span = pages * bs
+    lengths = [1, 5, span, min(2 * span, 28), 28, 27]
+    q, kp, vp, bt, cl = _setup(6, B=len(lengths), Hkv=Hkv, rep=rep, hd=hd,
+                               bs=bs, n_logical=n_logical, lengths=lengths,
+                               dtype=dtype)
+    bt = bt.at[0].set(0)
+    B = len(lengths)
+    out = paged_decode_kernel(
+        q.reshape(B, Hkv, rep, hd), kp[0].reshape(-1, bs, Hkv, hd),
+        vp[0].reshape(-1, bs, Hkv, hd), bt, cl, block_size=bs,
+        softcap=softcap, pages_per_step=pages, interpret=True)
+    out = np.asarray(out.reshape(q.shape), np.float32)
+    for ref in (paged_attention_ref, paged_decode_attention):
+        want = ref(q, kp, vp, bt, cl, block_size=bs, softcap=softcap)
+        np.testing.assert_allclose(out, np.asarray(want, np.float32),
+                                   atol=_tol(dtype), rtol=_tol(dtype))
+
+
+def test_decode_pages_per_step_from_shapes():
+    """P is the most pages whose K and V fit the step's byte target, from
+    each device's local kv heads, at least 1 and at most the table."""
+    from repro.kernels.paged_attention.paged_attention import \
+        DECODE_STEP_BYTES
+
+    def pool(hkv, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((1, 2801 * 16, hkv, 128), dtype)
+    page = 16 * 16 * 128 * 2                  # a 16-token page, 16 heads
+    cell = decode_plan(pool(16), block_size=16, n_blocks=288)
+    assert cell == {"kernel": "paged_decode",
+                    "pages_per_step": DECODE_STEP_BYTES // (2 * page)}
+    local = decode_plan(pool(4), block_size=16, n_blocks=288)
+    assert local["pages_per_step"] == 4 * cell["pages_per_step"]
+    assert decode_plan(pool(16), block_size=16,
+                       n_blocks=2)["pages_per_step"] == 2
+    assert decode_plan(pool(16, jnp.float32), block_size=4096,
+                       n_blocks=288)["pages_per_step"] == 1
+
+
 def test_paged_attention_block_size_sweep():
     """Kernel/reference parity holds at every block size (tile shape must
     not change the math)."""
@@ -115,12 +165,11 @@ def test_paged_attention_multiquery_parity(q_len, rep):
 
 def test_multiquery_last_query_aligns_with_single_query():
     """The last query of a verify window attends exactly the positions a
-    plain decode query at the same state does, so its output must agree
-    with the q_len=1 call to float-associativity noise (~ulps; XLA may
-    vectorize the two shapes differently).  The q_len == 1 path itself
-    runs the original single-query mask on its own static branch, so
-    plain decode through the extended kernel is bit-identical to the
-    pre-multi-query kernel by construction."""
+    plain decode query at the same state does, so the q-tiled kernel's
+    output for it must agree with the decode kernel's (the q_len=1 call)
+    to float-associativity noise: the two kernels scale the scores at
+    different points and fold the online softmax over different page
+    groupings."""
     bs, n_logical, S = 4, 6, 3
     lengths = [6, 9, 13]
     q, kp, vp, bt, cl = _setup(4, B=3, Hkv=2, rep=2, hd=16, bs=bs,
@@ -185,6 +234,13 @@ def test_engine_greedy_streams_identical_fused_vs_gather():
         outs, rep = captured_run(eng, reqs)
         assert rep["jit_entries"]["decode"] == 1
         assert rep["engine"]["fused_paged_attention"] is fused
+        decode = rep["attention_dispatch"]["decode"]
+        assert decode["fused"] is fused
+        if fused:
+            # the decode kernel, at the pages per step the pool's shape
+            # gives: the whole table, which is far under the byte target
+            assert decode["kernel"] == "paged_decode"
+            assert decode["pages_per_step"] == eng.block_table.shape[1]
         streams[fused] = outs
     assert streams[False] == streams[True]
 

@@ -7,13 +7,19 @@ VMEM fails here, where interpret-mode parity tests cannot see it.  Shapes
 are Qwen1.5-MoE's published widths in bf16 (16 heads x 128, d_model
 2048, 60 experts x d_ff 1408) at the shapes ``chip_smoke.py`` serves:
 8 decode slots over a paged pool of 16-token blocks, and 256-token
-prefill chunks over the slab scratch viewed as 128-token blocks.
+prefill chunks over the slab scratch viewed as 128-token blocks; and the
+decode kernel at the chip benchmark's shape, whose compiled custom call
+the benchmark's ``paged_attention_roofline`` reader must find.
 
 The topology is described only inside the module fixture: the TPU
 library admits one process at a time, and pytest workers import every
 test file.
 """
+import importlib.util
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +28,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core.grouped_ffn import grouped_ffn
-from repro.kernels.paged_attention.ops import paged_attention
+from repro.kernels.paged_attention.ops import decode_plan, paged_attention
 from repro.kernels.paged_attention.paged_attention import PagedLayoutError
 
 QWEN = get_config("qwen15-moe-a27b")
@@ -86,6 +92,54 @@ def test_paged_attention_rejects_unaligned_head_dim(one_chip):
                        block_size=16, n_blocks=8, pool_blocks=65)
     with pytest.raises(PagedLayoutError, match="head_dim % 128"):
         fn.lower(*args)
+
+
+def _reader_pattern(metric):
+    """The kernel pattern of one of the chip benchmark's roofline readers."""
+    bench = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    spec = importlib.util.spec_from_file_location(
+        f"reader_{metric}", bench / "metrics" / f"{metric}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.KERNEL
+
+
+def _custom_calls(compiled):
+    """The compiled module's Mosaic calls, printed as a chip trace names
+    its ops: with each operand's shape."""
+    from jax._src.lib import xla_client
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_metadata = False
+    opts.print_backend_config = False
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+# the qwen15-L6.chat cell's decode call (32 slots, 288-block tables over a
+# 2,800-block pool plus the null block), and one device's share of it on
+# four chips (4 kv heads), where the byte target gives the largest P
+@pytest.mark.parametrize("hkv", [H, H // 4])
+def test_paged_decode_compiles_for_the_benchmark_reader(one_chip, hkv):
+    fn = jax.jit(lambda q, k, v, bt, cl: paged_attention(
+        q, k, v, bt, cl, block_size=16, interpret=False))
+    args = _paged_args(one_chip, batch=32, q_len=1, hkv=hkv, hd=HD,
+                       block_size=16, n_blocks=288, pool_blocks=2801)
+    pages = decode_plan(args[1], block_size=16,
+                        n_blocks=288)["pages_per_step"]
+    assert pages == (H // hkv) * decode_plan(
+        _paged_args(one_chip, batch=32, q_len=1, hkv=H, hd=HD,
+                    block_size=16, n_blocks=288, pool_blocks=2801)[1],
+        block_size=16, n_blocks=288)["pages_per_step"]
+    calls = _custom_calls(fn.lower(*args).compile())
+    assert len(calls) == 1
+    assert any(re.search(p, calls[0])
+               for p in _reader_pattern("paged_attention_roofline"))
+    assert not any(re.search(p, calls[0])
+                   for p in _reader_pattern("moe_gmm_roofline"))
 
 
 @pytest.mark.parametrize("groups", [60, 15])   # one chip / one of four
