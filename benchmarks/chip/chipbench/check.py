@@ -55,22 +55,24 @@ def _inputs(it: Item):
     return tokens, positions
 
 
-def gaps(reference, key, dims, items: List[Item],
+def gaps(reference, layout, key, dims, items: List[Item],
          control: Optional[str] = None) -> Dict[str, float]:
     """``mean_logit_gap`` and ``max_logit_gap``: the mean and the widest
     gap of a served token below the reference's best, over ``tokens``
     served tokens; with ``control`` (a precision), ``control_mean_gap``
     and ``control_max_gap``: the same for the tokens the reference
-    computed in that precision puts first."""
+    computed in that precision puts first.  The reference reads its
+    weights from ``layout`` (the configuration's, whose ``Dims`` ``dims``
+    is)."""
     got, low = [], []
     for it in items:
         tokens, positions = _inputs(it)
-        ref = reference.logits(key, dims, tokens, positions)
+        ref = reference.logits(layout, key, dims, tokens, positions)
         best = ref.max(axis=1)
         rows = np.arange(len(it.served))
         got.append(best - ref[rows, it.served])
         if control is not None:
-            pick = reference.logits(key, dims, tokens, positions,
+            pick = reference.logits(layout, key, dims, tokens, positions,
                                     precision=control).argmax(axis=1)
             low.append(best - ref[rows, pick])
     got = np.concatenate(got) if got else np.zeros(0)

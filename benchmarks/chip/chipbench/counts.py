@@ -1,5 +1,6 @@
-"""Operations and bytes that one kernel call or one token needs, from
-shapes and token counts (the yardstick of the roofline and MFU metrics).
+"""Operations and bytes that one kernel call needs, from shapes and token
+counts (the yardstick of the roofline metrics; the FLOPs of one token,
+which ``step_mfu`` reads, are the layout's ``Dims.token_flops``).
 
 Counts are of the algorithm, not of what a kernel happens to do: rows are
 real routed rows (no tile padding), weights are those of the experts that
@@ -9,8 +10,6 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Iterable, Tuple
-
-from chipbench.weights import MoEDims
 
 
 @dataclass(frozen=True)
@@ -52,20 +51,3 @@ def paged_attention(queries: Iterable[Tuple[int, int]], heads: int,
         nbytes += (2 * ctx * kv_heads * head_dim
                    + 2 * q * heads * head_dim) * itemsize
     return Work(flops, nbytes)
-
-
-def token_flops(dims: MoEDims, context: int, *, logits: bool) -> float:
-    """Model FLOPs of one token through every layer at ``context``
-    positions (itself included): the projections, the router, its top-k
-    experts and the shared expert, causal attention over its context, and
-    the output head where the token's logits are computed."""
-    d, H, Hkv, hd = dims.d_model, dims.heads, dims.kv_heads, dims.head_dim
-    per_layer = (d * H * hd * 2 + d * Hkv * hd * 2          # q, o; k, v
-                 + d * dims.experts                          # router
-                 + dims.top_k * 3 * d * dims.d_expert        # experts
-                 + 3 * d * dims.d_shared)                    # shared
-    flops = 2.0 * per_layer * dims.layers
-    flops += 4.0 * H * hd * context * dims.layers
-    if logits:
-        flops += 2.0 * d * dims.vocab
-    return flops

@@ -8,7 +8,6 @@ from chipbench.client import StepRec, Window
 from chipbench.counts import Work
 from chipbench.peaks import Peaks
 from chipbench.system import Call
-from chipbench.weights import MoEDims
 from chipbench.xtrace import Reduction
 
 
@@ -16,7 +15,7 @@ from chipbench.xtrace import Reduction
 class Run:
     window: Window                # steps, requests, the engine's report
     calls: List[Call]             # every jitted prefill / decode call
-    dims: MoEDims
+    dims: Any                     # the configuration's layout's Dims
     config: Dict[str, Any]        # the configuration file
     mix: Dict[str, Any]           # the traffic mix
     peaks: Optional[Peaks]

@@ -2,11 +2,12 @@
 own entry points from a configuration file, a traffic mix and a seed.
 
 ``launch/serve.build_parser`` and ``config_from_args`` give the model
-configuration (the file sets its depth and RoPE base and must match its
-widths), ``build_model`` / ``param_shardings`` the model and its layout,
+configuration (the configuration's layout sets what the file fixes, such
+as its depth, and the program must match every width the file states),
+``build_model`` / ``param_shardings`` the model and its sharding,
 ``engine_config_for`` the engine's shapes, ``ServeEngine`` the engine.
-The weights are the benchmark's (``weights.fill_program_tree``), made on
-the device in one jitted call from the seed.
+The weights are the benchmark's (the layout's ``fill_program_tree``),
+made on the device in one jitted call from the seed.
 
 ``Recorder`` wraps the engine's two jitted step entries and its finish
 hook from outside: it records what each call was given (positions, the
@@ -33,18 +34,6 @@ import numpy as np
 from chipbench import traffic, weights
 
 
-def program_dims(cfg) -> weights.MoEDims:
-    moe = cfg.moe
-    return weights.MoEDims(
-        layers=cfg.num_layers, d_model=cfg.d_model, heads=cfg.num_heads,
-        kv_heads=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
-        experts=moe.num_experts, top_k=moe.num_experts_per_tok,
-        d_expert=moe.d_ff_expert,
-        d_shared=moe.num_shared_experts * moe.d_ff_expert,
-        vocab=cfg.vocab_size, rope_theta=cfg.rope_theta, norm_eps=1e-6,
-        dtype=cfg.dtype)
-
-
 def serve_argv(cfg_doc: Dict[str, Any], mix: Dict[str, Any],
                seed32: int) -> List[str]:
     dep = cfg_doc["deployment"]
@@ -68,19 +57,25 @@ def serve_argv(cfg_doc: Dict[str, Any], mix: Dict[str, Any],
     return argv
 
 
-def model_config(cfg_doc: Dict[str, Any], args):
-    """The program's configuration for ``cfg_doc``: its registered arch at
-    the file's depth and RoPE base; every width must equal the file's."""
+def model_config(cfg_doc: Dict[str, Any], layout, args):
+    """The program's configuration for ``cfg_doc`` and the widths both
+    state: the registered arch as ``layout.program_config`` sets it; every
+    width the layout reads from the program must equal the file's (those
+    in ``layout.FROM_FILE`` are the file's alone)."""
     from repro.launch.serve import config_from_args
-    want = weights.MoEDims.from_config(cfg_doc)
-    cfg = config_from_args(args).replace(num_layers=want.layers,
-                                         rope_theta=want.rope_theta,
-                                         dtype=want.dtype)
-    got = dataclasses.replace(program_dims(cfg), norm_eps=want.norm_eps)
+    want = layout.from_config(cfg_doc)
+    cfg = layout.program_config(config_from_args(args), want)
+    got = dataclasses.replace(
+        layout.from_program(cfg),
+        **{f: getattr(want, f) for f in layout.FROM_FILE})
     if got != want:
+        diff = ", ".join(
+            f"{f.name}: program {getattr(got, f.name)!r}, file "
+            f"{getattr(want, f.name)!r}" for f in dataclasses.fields(want)
+            if getattr(got, f.name) != getattr(want, f.name))
         raise ValueError(f"the program's {cfg.name} differs from the "
-                         f"configuration file: {got} != {want}")
-    return cfg
+                         f"configuration file ({diff})")
+    return cfg, want
 
 
 @dataclass
@@ -149,14 +144,15 @@ class Recorder:
 
 @dataclass
 class System:
-    dims: weights.MoEDims
+    dims: Any                     # the layout's Dims
     engine: Any                   # the program's ServeEngine
     recorder: Recorder
 
 
-def build(cfg_doc: Dict[str, Any], mix: Dict[str, Any], seed: int,
+def build(cfg_doc: Dict[str, Any], layout, mix: Dict[str, Any], seed: int,
           diags: bool = False) -> System:
-    """The engine with its recorder; ``diags`` turns on the decode
+    """The engine with its recorder, its weights filled by ``layout`` (the
+    configuration's ``layouts/<name>.py``); ``diags`` turns on the decode
     step's per-layer expert loads (traced runs)."""
     from repro.configs.base import ParallelConfig
     from repro.launch.mesh import make_host_mesh
@@ -168,8 +164,7 @@ def build(cfg_doc: Dict[str, Any], mix: Dict[str, Any], seed: int,
     seed32 = int(np.random.SeedSequence(int(seed)).generate_state(1)[0]
                  & 0x7FFFFFFF)
     args = build_parser().parse_args(serve_argv(cfg_doc, mix, seed32))
-    cfg = model_config(cfg_doc, args)
-    dims = program_dims(cfg)
+    cfg, dims = model_config(cfg_doc, layout, args)
     dep = cfg_doc["deployment"]
     pcfg = ParallelConfig(attn_chunk=min(512, args.prompt_len))
     mesh = make_host_mesh(data=1, model=dep["model_par"])
@@ -178,7 +173,7 @@ def build(cfg_doc: Dict[str, Any], mix: Dict[str, Any], seed: int,
                         mesh_shape=ms, mesh=mesh)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     shardings = param_shardings(shapes, cfg, pcfg, mesh)
-    fill = jax.jit(lambda k: weights.fill_program_tree(
+    fill = jax.jit(lambda k: layout.fill_program_tree(
         shapes, dims, dep["model_par"], k), out_shardings=shardings)
     params = fill(weights.seed_key(seed))
     ecfg = engine_config_for(

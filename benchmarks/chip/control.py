@@ -39,8 +39,8 @@ def main() -> None:
         cc = sv.config["check"]
         chosen = check.sample(sv.items, seed, cc["sample_tokens"])
         t1 = time.perf_counter()
-        got = check.gaps(sv.reference, weights.seed_key(seed), sv.dims,
-                         chosen, control=cc["control"])
+        got = check.gaps(sv.reference, sv.layout, weights.seed_key(seed),
+                         sv.dims, chosen, control=cc["control"])
         print(json.dumps({
             "seed": seed, "requests": len(sv.items),
             "sampled": [it.rid for it in chosen], **got,

@@ -1,9 +1,8 @@
 """step_mfu: model FLOPs of the tokens the traced window processed (every
 prefill and decode token through the projections, router, top-k and
 shared experts and causal attention, plus the output head where logits
-were taken; ``counts.token_flops``), over the traced window's length
-times the chips times the chip's peak bf16 FLOP/s, in percent."""
-from chipbench.counts import token_flops
+were taken; the layout's ``Dims.token_flops``), over the traced window's
+length times the chips times the chip's peak bf16 FLOP/s, in percent."""
 
 
 def read(run):
@@ -13,8 +12,8 @@ def read(run):
     for c in run.traced_calls():
         for q, ctx in c.rows:
             for i in range(q):
-                flops += token_flops(run.dims, ctx - q + i + 1,
-                                     logits=(i == q - 1))
+                flops += run.dims.token_flops(ctx - q + i + 1,
+                                              logits=(i == q - 1))
     if not flops:
         return None
     return flops / (run.trace.window_s * run.chips

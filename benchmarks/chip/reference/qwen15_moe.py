@@ -2,9 +2,9 @@
 
 One sequence at a time, one layer at a time, in ``jax.numpy`` with every
 matrix product at ``Precision.HIGHEST``; no cache, no kernels, no
-batching, no capacity.  The weights are the benchmark's
-(``chipbench.weights``), regenerated here from the seed; nothing is taken
-from the program.
+batching, no capacity.  The weights are the benchmark's, regenerated here
+from the seed by the configuration's layout (``layouts/qwen2_moe.py``,
+handed in as ``W``); nothing is taken from the program.
 
 Per layer, on the residual stream ``h`` (positions 0..T-1):
 
@@ -37,8 +37,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from chipbench import weights as W
 
 HI = jax.lax.Precision.HIGHEST
 # sequences are padded to a multiple of BUCKET (three lengths up to the
@@ -99,8 +97,8 @@ def _attention(q, k, v, hd):
     return out.reshape(T, *q.shape[1:])
 
 
-@partial(jax.jit, static_argnames=("dims", "precision"))
-def _layer(key, l, h, *, dims: W.MoEDims, precision: str):
+@partial(jax.jit, static_argnames=("W", "dims", "precision"))
+def _layer(key, l, h, *, W, dims, precision: str):
     p = W.layer(key, l, dims)
     hd = dims.head_dim
     a = _rms(h, p["norm1"], dims.norm_eps)
@@ -132,30 +130,32 @@ def _layer(key, l, h, *, dims: W.MoEDims, precision: str):
     return h + y
 
 
-@partial(jax.jit, static_argnames=("dims",))
-def _embed(key, tokens, *, dims: W.MoEDims):
+@partial(jax.jit, static_argnames=("W", "dims"))
+def _embed(key, tokens, *, W, dims):
     return W.table(key, "embed", dims)[tokens].astype(jnp.float32)
 
 
-@partial(jax.jit, static_argnames=("dims", "precision"))
-def _head(key, h, *, dims: W.MoEDims, precision: str):
+@partial(jax.jit, static_argnames=("W", "dims", "precision"))
+def _head(key, h, *, W, dims, precision: str):
     x = _rms(h, W.final_norm(key, dims), dims.norm_eps)
     head = W.table(key, "lm_head", dims).astype(jnp.float32)
     return _mm(x, head, precision, "td,vd->tv")
 
 
-def logits(key, dims: W.MoEDims, tokens: np.ndarray, positions: np.ndarray,
+def logits(W, key, dims, tokens: np.ndarray, positions: np.ndarray,
            precision: str = "f32") -> np.ndarray:
-    """Logits [len(positions), vocab] at ``positions`` of ``tokens``."""
+    """Logits [len(positions), vocab] at ``positions`` of ``tokens``, with
+    the weights of layout ``W`` (whose ``Dims`` ``dims`` is)."""
     T = len(tokens)
     Tp = -(-T // BUCKET) * BUCKET
     toks = np.zeros((Tp,), np.int32)
     toks[:T] = tokens
-    h = _embed(key, jnp.asarray(toks), dims=dims)
+    h = _embed(key, jnp.asarray(toks), W=W, dims=dims)
     for l in range(dims.layers):
-        h = _layer(key, jnp.int32(l), h, dims=dims, precision=precision)
+        h = _layer(key, jnp.int32(l), h, W=W, dims=dims, precision=precision)
     P = len(positions)
     pos = np.zeros((-(-P // ROWS) * ROWS,), np.int32)
     pos[:P] = positions
-    out = _head(key, h[jnp.asarray(pos)], dims=dims, precision=precision)
+    out = _head(key, h[jnp.asarray(pos)], W=W, dims=dims,
+                precision=precision)
     return np.asarray(out[:P])

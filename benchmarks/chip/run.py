@@ -90,8 +90,9 @@ class Served:
     items: List[check.Item]
     bad_lengths: int
     compiles: int
-    dims: weights.MoEDims
+    dims: object                  # the layout's Dims
     config: dict
+    layout: object                # the configuration's layout module
     reference: object
     window: client.Window
 
@@ -108,6 +109,7 @@ def serve(workload: str, seed: int, seconds: float, trace: bool, *,
     cfg_doc = registry.load_json("configs", cell["config"], base)
     mix = {**registry.load_json("traffic", cell["traffic"], base),
            **(mix_update or {})}
+    layout = registry.layout(cell["config"], cfg_doc, base)
     reference = registry.module("reference", cfg_doc["reference"], base)
     readers = {m["name"]: (m, registry.module("metrics", m["name"], base))
                for m in registry.metrics_for(bench, workload, trace)}
@@ -118,7 +120,7 @@ def serve(workload: str, seed: int, seconds: float, trace: bool, *,
     kind = devices[0].device_kind
     peaks = peaks_of(kind) if require_chip else PEAKS.get(kind)
 
-    sut = system.build(cfg_doc, mix, seed, diags=trace)
+    sut = system.build(cfg_doc, layout, mix, seed, diags=trace)
     if fault is not None:
         fault(sut)
     engine, rec = sut.engine, sut.recorder
@@ -174,14 +176,15 @@ def serve(workload: str, seed: int, seconds: float, trace: bool, *,
     gc.collect()
     return Served(out=out, items=items, bad_lengths=bad,
                   compiles=win.compiles, dims=dims, config=cfg_doc,
-                  reference=reference, window=win)
+                  layout=layout, reference=reference, window=win)
 
 
 def judge(sv: Served, seed: int) -> dict:
     """The check, with the program's state freed: the result object."""
     cc = sv.config["check"]
     chosen = check.sample(sv.items, seed, cc["sample_tokens"])
-    got = check.gaps(sv.reference, weights.seed_key(seed), sv.dims, chosen)
+    got = check.gaps(sv.reference, sv.layout, weights.seed_key(seed),
+                     sv.dims, chosen)
     checks = {
         "mean_logit_gap": {"value": got["mean_logit_gap"],
                            "limit": cc["mean_logit_gap"]},

@@ -1,7 +1,8 @@
 """A tiny benchmark tree for the harness tests: the reduced Qwen1.5-MoE
 (d_model 128, 8 experts, top-2, served in bfloat16) as a configuration, a short
 Poisson mix, and a BENCHMARK.json naming them, written into a directory;
-the cell's metrics, arrival processes and reference are the real ones."""
+the cell's metrics, arrival processes, layout and reference are the real
+ones."""
 from __future__ import annotations
 
 import json
@@ -22,6 +23,7 @@ CONFIG = {
     "num_hidden_layers": 2, "rope_theta": 1000000.0, "rms_norm_eps": 1e-06,
     "vocab_size": 512, "torch_dtype": "bfloat16",
     "program": {"arch": "qwen15-moe-a27b", "reduced": True},
+    "layout": "qwen2_moe",
     "reference": "qwen15_moe",
     "deployment": {"chips": 1, "model_par": 1, "max_slots": 4,
                    "prefill_chunk": 16, "kv_block_size": 8, "kv_blocks": 40,
@@ -43,19 +45,11 @@ MIX = {
 CELL = "tiny.chat"
 
 
-def write_tree(root: Path, dtype: str = "bfloat16") -> Path:
-    """configs/, traffic/, metrics/, arrivals/ and reference/ under
-    ``root`` (the last three linked to the benchmark's own) and
-    BENCHMARK.json; the model is served in ``dtype``."""
-    for kind in ("configs", "traffic"):
-        (root / kind).mkdir(parents=True, exist_ok=True)
-    (root / "configs" / "tiny.json").write_text(
-        json.dumps({**CONFIG, "torch_dtype": dtype}))
-    (root / "traffic" / "tiny.json").write_text(json.dumps(MIX))
-    for kind in ("metrics", "arrivals", "reference"):
-        os.symlink(BENCH / kind, root / kind)
-    bench = {
-        "workloads": [{"name": CELL, "config": "tiny", "traffic": "tiny",
+def benchmark(cell: str, config: str, traffic: str) -> dict:
+    """A BENCHMARK.json of one one-chip cell with the chat cell's
+    end-to-end metrics and one per-layer metric."""
+    return {
+        "workloads": [{"name": cell, "config": config, "traffic": traffic,
                        "chips": 1, "why": "tests"}],
         "end_to_end": [
             {"name": n, "unit": u, "better": "lower", "bound": 0.25,
@@ -67,5 +61,19 @@ def write_tree(root: Path, dtype: str = "bfloat16") -> Path:
              "source": "program_span", "layer": "engine step loop",
              "moves": "itl_p90_ms"}],
     }
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+def write_tree(root: Path, dtype: str = "bfloat16") -> Path:
+    """configs/, traffic/, metrics/, arrivals/, layouts/ and reference/
+    under ``root`` (the last four linked to the benchmark's own) and
+    BENCHMARK.json; the model is served in ``dtype``."""
+    for kind in ("configs", "traffic"):
+        (root / kind).mkdir(parents=True, exist_ok=True)
+    (root / "configs" / "tiny.json").write_text(
+        json.dumps({**CONFIG, "torch_dtype": dtype}))
+    (root / "traffic" / "tiny.json").write_text(json.dumps(MIX))
+    for kind in ("metrics", "arrivals", "layouts", "reference"):
+        os.symlink(BENCH / kind, root / kind)
+    (root / "BENCHMARK.json").write_text(
+        json.dumps(benchmark(CELL, "tiny", "tiny")))
     return root

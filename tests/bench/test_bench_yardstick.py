@@ -15,12 +15,11 @@ import bench_tiny  # noqa: F401  (puts benchmarks/chip on the path)
 from chipbench import client, counts, registry, stats, system, traffic, xtrace
 from chipbench.peaks import peaks_of
 from chipbench.record import Run
-from chipbench.weights import MoEDims
 
-QWEN_L6 = MoEDims(layers=6, d_model=2048, heads=16, kv_heads=16,
-                  head_dim=128, experts=60, top_k=4, d_expert=1408,
-                  d_shared=5632, vocab=151936, rope_theta=1e6,
-                  norm_eps=1e-6)
+QWEN_L6 = registry.module("layouts", "qwen2_moe").Dims(
+    layers=6, d_model=2048, heads=16, kv_heads=16, head_dim=128, experts=60,
+    top_k=4, d_expert=1408, d_shared=5632, vocab=151936, rope_theta=1e6,
+    norm_eps=1e-6)
 
 
 # device op names as a v5e trace spells them (the decode-step kernels of
@@ -160,9 +159,9 @@ def test_token_flops_at_qwen_widths():
                  + 4 * 3 * 2048 * 1408 + 3 * 2048 * 5632)
     assert per_layer == 86_106_112
     base = 2 * per_layer * 6 + 4 * 16 * 128 * 1000 * 6
-    assert counts.token_flops(QWEN_L6, 1000, logits=False) == base \
+    assert QWEN_L6.token_flops(1000, logits=False) == base \
         == 1_082_425_344
-    assert counts.token_flops(QWEN_L6, 1000, logits=True) == \
+    assert QWEN_L6.token_flops(1000, logits=True) == \
         base + 2 * 2048 * 151936
 
 
@@ -318,6 +317,10 @@ def test_the_benchmark_names_files_that_exist():
     for cell in b["workloads"]:
         cfg = registry.load_json("configs", cell["config"])
         registry.load_json("traffic", cell["traffic"])
+        layout = registry.layout(cell["config"], cfg)
+        for name in ("Dims", "FROM_FILE", "from_config", "from_program",
+                     "program_config", "fill_program_tree"):
+            assert hasattr(layout, name), name
         assert hasattr(registry.module("reference", cfg["reference"]),
                        "logits")
         for traced in (False, True):
